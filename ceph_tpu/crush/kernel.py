@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_enable_x64", True)  # straw2 draws are int64 fixed-point
-
 from ceph_tpu.crush import ln_table
 from ceph_tpu.crush.map import (
     CRUSH_BUCKET_STRAW2, CRUSH_RULE_CHOOSELEAF_FIRSTN,
@@ -39,7 +37,14 @@ from ceph_tpu.crush.map import (
 )
 from ceph_tpu.ops import rjenkins
 
-S64_MIN = jnp.int64(-(2**63))
+# straw2 draws are int64 fixed-point: the map arrays are built, and
+# every rule program traced and dispatched, under a SCOPED
+# jax.enable_x64(True) — never process-wide, where it would turn the
+# Pallas kernels' index maps into i64 that Mosaic refuses to lower
+S64_MIN = -(2**63)
+# inputs per dispatch: the vmapped while-loops' temporaries grow with
+# the batch (the whole 2^20-input batch needed most of a v5e's HBM)
+MAX_BATCH = 1 << 16
 UNDEF = jnp.int32(-0x7FFFFFFF)
 NONE = jnp.int32(-0x80000000)
 
@@ -86,10 +91,12 @@ class DenseMap:
 
         max_depth = max((bucket_depth(b) for b in cmap.buckets), default=1)
         w = weight if weight is not None else cmap.full_weight_vector()
-        return cls(items=jnp.asarray(items), weights=jnp.asarray(weights),
-                   sizes=jnp.asarray(sizes), types=jnp.asarray(types),
-                   dev_weight=jnp.asarray(np.asarray(w, dtype=np.int64)),
-                   max_devices=cmap.max_devices, max_depth=max_depth)
+        with jax.enable_x64(True):
+            return cls(items=jnp.asarray(items),
+                       weights=jnp.asarray(weights),
+                       sizes=jnp.asarray(sizes), types=jnp.asarray(types),
+                       dev_weight=jnp.asarray(np.asarray(w, dtype=np.int64)),
+                       max_devices=cmap.max_devices, max_depth=max_depth)
 
 
 def crush_ln_jax(u):
@@ -119,7 +126,8 @@ def _straw2_row(dm: DenseMap, row, x, r):
                           jnp.uint32(r & 0xFFFFFFFF), xp=jnp)
     u = (u & jnp.uint32(0xFFFF)).astype(jnp.int64)
     ln = crush_ln_jax(u) - jnp.int64(0x1000000000000)
-    draws = jnp.where(mask & (ws > 0), -((-ln) // jnp.maximum(ws, 1)), S64_MIN)
+    draws = jnp.where(mask & (ws > 0), -((-ln) // jnp.maximum(ws, 1)),
+                      jnp.int64(S64_MIN))
     return ids[jnp.argmax(draws)]
 
 
@@ -297,7 +305,11 @@ def compile_rule(cmap: CrushMap, ruleno: int, result_max: int,
     """Build a jitted bulk evaluator for one rule: xs (N,) -> (N, result_max).
 
     Unplaced firstn slots hold CRUSH_ITEM_NONE at the tail; indep holds NONE
-    in place, mirroring crush_do_rule's output contract.
+    in place, mirroring crush_do_rule's output contract.  Inputs past
+    MAX_BATCH run as padded dispatches of one compiled shape.
+
+    `run.trace_one` embeds the single-input evaluator in a caller's own
+    trace, which must then run under ``jax.enable_x64(True)``.
     """
     dm = DenseMap.from_crush_map(cmap, weight)
     rule = cmap.rules[ruleno]
@@ -314,6 +326,10 @@ def compile_rule(cmap: CrushMap, ruleno: int, result_max: int,
             "chained choose steps: use the host mapper")
 
     def one(x):
+        if not jax.config.jax_enable_x64:
+            raise RuntimeError(
+                "CRUSH rule traced without jax.enable_x64(True): the "
+                "int64 straw2 draws would silently truncate")
         x = x.astype(jnp.int32)
         choose_tries = cmap.choose_total_tries + 1
         choose_leaf_tries = 0
@@ -374,7 +390,18 @@ def compile_rule(cmap: CrushMap, ruleno: int, result_max: int,
     batched = jax.jit(jax.vmap(one))
 
     def run(xs) -> np.ndarray:
-        return np.asarray(batched(jnp.asarray(xs, dtype=jnp.int32)))
+        xs = np.asarray(xs, dtype=np.int32)
+        with jax.enable_x64(True):
+            if len(xs) <= MAX_BATCH:
+                return np.asarray(batched(xs))
+            out = np.empty((len(xs), result_max), dtype=np.int32)
+            for lo in range(0, len(xs), MAX_BATCH):
+                part = xs[lo:lo + MAX_BATCH]
+                n = len(part)
+                if n < MAX_BATCH:
+                    part = np.pad(part, (0, MAX_BATCH - n))
+                out[lo:lo + n] = np.asarray(batched(part))[:n]
+            return out
 
     run.dense_map = dm
     run.trace_one = one  # traceable single-x evaluator for shard_map/pjit use

@@ -75,7 +75,15 @@ class ErasureCodeJax(ErasureCode):
             "jerasure-per-chunk-alignment", profile, "false")
         if self.technique.startswith("cauchy"):
             self.packetsize = to_int("packetsize", profile, "2048")
-        self.use_tpu = to_bool("tpu", profile, "true") and gf.backend_available()
+        want_tpu = to_bool("tpu", profile, "true")
+        self.use_tpu = want_tpu and gf.backend_available()
+        if want_tpu and not self.use_tpu:
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "ec_jax %s k=%s m=%s: tpu requested, no jax backend;"
+                " the codec runs on the host", self.technique,
+                profile.get("k"), profile.get("m"))
         self.tpu_min_bytes = to_int("tpu-min-bytes", profile, "1")
         self.use_plan = to_bool("plan-cache", profile, "true")
         self.sanity_check_k_m(self.k, self.m)

@@ -132,8 +132,9 @@ def stats() -> dict:
 
     hits/misses count plan-cache lookups; retraces counts actual XLA
     traces (each is one compile); per_plan maps plan labels to
-    dispatch counts and cumulative dispatch seconds (host-side
-    dispatch time — device completion is asynchronous).
+    dispatch counts, cumulative dispatch seconds (host-side
+    dispatch time — device completion is asynchronous) and the
+    executor that served them (e.g. ``pallas_words+crc``).
     """
     with _lock:
         out = {
@@ -182,11 +183,12 @@ def _note_retrace(label: str) -> None:
         entry["retraces"] += 1
 
 
-def _note_dispatch(label: str, seconds: float) -> None:
+def _note_dispatch(label: str, seconds: float, executor: str) -> None:
     with _lock:
         _counters["dispatches"] += 1
         entry = _per_plan.setdefault(
             label, {"dispatches": 0, "seconds": 0.0, "retraces": 0})
+        entry["executor"] = executor
         entry["dispatches"] += 1
         entry["seconds"] += seconds
 
@@ -322,7 +324,7 @@ class ExecPlan:
     def __call__(self, *args):
         t0 = time.perf_counter()
         out = self.fn(*args)
-        _note_dispatch(self.label, time.perf_counter() - t0)
+        _note_dispatch(self.label, time.perf_counter() - t0, self.executor)
         return out
 
 
@@ -1419,14 +1421,22 @@ def fused_encode_crc_step(mbits, d, consts):
     return parity, cks.crc32c_pack_bits(bits)
 
 
-def _build_encode_crc(key: tuple) -> ExecPlan:
+def _build_encode_crc(key: tuple, matrix: np.ndarray) -> ExecPlan:
     """Fused parity + per-chunk zero-seeded crc32c in ONE dispatch
     (parity and the ECUtil::HashInfo ledger used to be two round
     trips).  The chunk-byte axis is NOT bucketed here — a CRC is
     length-exact — so the key carries the exact S; only the stripe
     batch pads (padded stripes' crcs are sliced off with the parity).
+    Where the Pallas kernels take the shape (a TPU, S a multiple of
+    512 and at most 8 KiB) they serve it; the XLA bit-matmul
+    otherwise.
     """
-    s = key[5]
+    from ceph_tpu.ops import crc_pallas, gf_pallas
+
+    _sig, _kind, rows, k, bb, s = key[:6]
+    if gf_pallas.supported((bb, k, s)) and \
+            crc_pallas.supported(s, bb * (k + rows)):
+        return _build_encode_crc_pallas(key, matrix)
     consts = cks.make_crc_consts(s)
 
     def impl(mbits, d):
@@ -1434,6 +1444,39 @@ def _build_encode_crc(key: tuple) -> ExecPlan:
 
     jfn = tracked_jit(_label(key), impl)
     return ExecPlan(key, jfn, "xla_bits+crc")
+
+
+def fused_encode_crc_words(coeffs: np.ndarray, words):
+    """The fused step on the Pallas kernels: the packed-word GF kernel
+    (ops/gf_pallas.py) for parity and the MXU crc kernel
+    (ops/crc_pallas.py) for every chunk's zero-seeded crc32c, over
+    (B, K, S//512, 128) int32 words -> (parity words, (B, K+M) crcs)."""
+    from ceph_tpu.ops import crc_pallas, gf_pallas
+
+    # traced inside the plan's tracked_jit, dispatched by _guarded
+    parity = gf_pallas.gf_matmul_words(coeffs, words)  # lint: disable=unguarded-device-dispatch
+    chunks = jnp.concatenate([words, parity], axis=1)
+    b, n, r4, _ = chunks.shape
+    s = r4 * 512
+    crcs = crc_pallas.crc32c_blocks_words(
+        chunks.reshape(b * n, s // 4), s, init=0)
+    return parity, crcs.reshape(b, n)
+
+
+def _build_encode_crc_pallas(key: tuple, matrix: np.ndarray) -> ExecPlan:
+    """The fused plan over the word view of the host bytes (free both
+    ways: gf_pallas.words_from_bytes / bytes_from_words)."""
+    from ceph_tpu.ops import gf_pallas
+
+    coeffs = np.array(matrix, dtype=np.uint8)
+    jfn = tracked_jit(_label(key),
+                      lambda words: fused_encode_crc_words(coeffs, words))
+
+    def run(_mbits, padded):
+        parity, crcs = jfn(gf_pallas.words_from_bytes(padded))
+        return gf_pallas.bytes_from_words(np.asarray(parity)), crcs
+
+    return ExecPlan(key, run, "pallas_words+crc")
 
 
 def encode_with_crc(matrix: np.ndarray, data: np.ndarray,
@@ -1476,9 +1519,10 @@ def encode_with_crc(matrix: np.ndarray, data: np.ndarray,
     key = plan_key(sig, "encode_crc", rows, k, b, s)
     if _quarantined(key):
         return None
-    plan = _get_plan(key, lambda: _build_encode_crc(key))
-    bb = key[4]
-    padded = jnp.asarray(_pad_batch(arr, bb, s))
+    plan = _get_plan(key, lambda: _build_encode_crc(key, matrix))
+    # host bytes go in as they are: the transfer lands inside the
+    # guarded body, under the watchdog
+    padded = _pad_batch(arr, key[4], s)
     status, out = _guarded("fused-crc", key, plan,
                            (_mbits_for(matrix), padded), b)
     if status == "oom" and b > 1:

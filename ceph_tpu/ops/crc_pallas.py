@@ -123,9 +123,11 @@ if HAVE_JAX:
             acc = d if acc is None else acc + d
         o_ref[...] = acc & 1
 
+    from ceph_tpu.ops.gf_pallas import x32
+
     @functools.lru_cache(maxsize=8)
-    def _crc_call(n_tiles: int, w: int):
-        return pl.pallas_call(
+    def _crc_call(n_tiles: int, w: int, interpret: bool = False):
+        return x32(pl.pallas_call(
             _crc_kernel,
             grid=(n_tiles,),
             in_specs=[
@@ -138,8 +140,8 @@ if HAVE_JAX:
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((n_tiles * _BT, 128),
                                            jnp.int32),
-            interpret=FORCE_INTERPRET,
-        )
+            interpret=interpret,
+        ))
 
     def crc32c_blocks_words(words, length: int, init: int = 0xFFFFFFFF):
         """crc32c of every `length`-byte block, blocks given as int32
@@ -156,7 +158,8 @@ if HAVE_JAX:
         pad = -n_blocks % _BT
         if pad:
             words = jnp.pad(words, ((0, pad), (0, 0)))
-        bits = _crc_call((n_blocks + pad) // _BT, w)(words, mk)
+        bits = _crc_call((n_blocks + pad) // _BT, w,
+                         FORCE_INTERPRET)(words, mk)
         crcs = jnp.sum(
             bits[:n_blocks, :32].astype(jnp.uint32)
             << jnp.arange(32, dtype=jnp.uint32),

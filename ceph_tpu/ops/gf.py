@@ -45,9 +45,9 @@ def backend_available() -> bool:
     """True when a jax backend actually initializes.
 
     `import jax` succeeding does not guarantee a usable backend (e.g.
-    JAX_PLATFORMS names a plugin that fails to load outside its home
-    directory); everything that device-dispatches must gate on this and
-    fall back to the host path."""
+    JAX_PLATFORMS names a plugin that fails to load); everything that
+    device-dispatches gates on this and takes the host path — and the
+    failure is logged once, so the host path is never taken unseen."""
     global _backend_ok
     if _backend_ok is None:
         if not HAVE_JAX:
@@ -56,8 +56,13 @@ def backend_available() -> bool:
             try:
                 jax.devices()
                 _backend_ok = True
-            except Exception:
+            except Exception as e:
                 _backend_ok = False
+                import logging
+
+                logging.getLogger(__name__).warning(
+                    "no jax backend (%r): every device path runs on the"
+                    " host", e)
     return _backend_ok
 
 # ---------------------------------------------------------------------------

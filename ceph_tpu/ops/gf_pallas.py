@@ -173,11 +173,25 @@ if HAVE_JAX:
                     acc = term if acc is None else acc ^ term
             o_ref[0, j] = acc
 
+    def x32(call):
+        """Trace a pallas_call with x64 off, whatever the process or
+        caller state: under x64 the BlockSpec index maps trace to i64,
+        which Mosaic refuses to lower ("failed to legalize operation
+        'func.return'")."""
+
+        @functools.wraps(call)
+        def run(*args):
+            with jax.enable_x64(False):
+                return call(*args)
+
+        return run
+
     @functools.lru_cache(maxsize=128)
-    def _spec_call(coeffs, b: int, r4: int, ts: int):
+    def _spec_call(coeffs, b: int, r4: int, ts: int,
+                   interpret: bool = False):
         r, k = len(coeffs), len(coeffs[0])
         kern = functools.partial(_spec_kernel, coeffs=coeffs, k=k, r=r)
-        return pl.pallas_call(
+        return x32(pl.pallas_call(
             kern,
             grid=(b, r4 // ts),
             in_specs=[pl.BlockSpec((1, k, ts, 128),
@@ -187,13 +201,14 @@ if HAVE_JAX:
                                    lambda bi, ti: (bi, 0, ti, 0),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((b, r, r4, 128), jnp.int32),
-            interpret=FORCE_INTERPRET,
-        )
+            interpret=interpret,
+        ))
 
     @functools.lru_cache(maxsize=64)
-    def _gen_call(r: int, k: int, b: int, r4: int, ts: int):
+    def _gen_call(r: int, k: int, b: int, r4: int, ts: int,
+                  interpret: bool = False):
         kern = functools.partial(_gen_kernel, k=k, r=r)
-        return pl.pallas_call(
+        return x32(pl.pallas_call(
             kern,
             grid=(b, r4 // ts),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -204,8 +219,8 @@ if HAVE_JAX:
                                    lambda bi, ti: (bi, 0, ti, 0),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((b, r, r4, 128), jnp.int32),
-            interpret=FORCE_INTERPRET,
-        )
+            interpret=interpret,
+        ))
 
     def _pick_ts(r4: int) -> int:
         ts = min(_TS, r4)
@@ -223,9 +238,9 @@ if HAVE_JAX:
         assert kk == k and lanes == 128, (words.shape, matrix.shape)
         ts = _pick_ts(r4)
         if key in _registered:
-            return _spec_call(key, b, r4, ts)(words)
+            return _spec_call(key, b, r4, ts, FORCE_INTERPRET)(words)
         mwords = jnp.asarray(np.asarray(matrix, np.uint8).astype(np.int32))
-        return _gen_call(r, k, b, r4, ts)(mwords, words)
+        return _gen_call(r, k, b, r4, ts, FORCE_INTERPRET)(mwords, words)
 
     def gf_matmul_words_runtime(mwords, words):
         """Traceable words-kernel entry: the (R,K) coefficient matrix is
@@ -235,7 +250,8 @@ if HAVE_JAX:
         b, k, r4, lanes = words.shape
         r = mwords.shape[0]
         assert lanes == 128
-        return _gen_call(r, k, b, r4, _pick_ts(r4))(mwords, words)
+        return _gen_call(r, k, b, r4, _pick_ts(r4),
+                         FORCE_INTERPRET)(mwords, words)
 
     def gf_matmul_pallas(matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
         """Host entry: (..., K, S) uint8 numpy -> (..., R, S) uint8 numpy

@@ -31,6 +31,9 @@ async def _main() -> None:
     ap.add_argument("--config", type=str, default="{}",
                     help="JSON osd config overrides")
     args = ap.parse_args()
+    from ceph_tpu.common import jaxcache
+
+    jaxcache.enable()
     try:
         if args.store_path:
             from ceph_tpu.os.tpustore import TPUStore

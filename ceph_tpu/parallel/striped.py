@@ -24,7 +24,6 @@ Decode runs the same matmul with host-inverted decode rows
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import List, Optional
 
 import jax
@@ -38,19 +37,8 @@ from ceph_tpu.ops import gf
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map with the pre-0.6 spelling as fallback: older jax
-    ships it as jax.experimental.shard_map.shard_map, and the
-    replication-check knob was renamed check_rep -> check_vma
-    independently of the move, so pick it off the actual signature
-    (0.5.x-era releases have jax.shard_map but still say check_rep)."""
-    if hasattr(jax, "shard_map"):
-        params = inspect.signature(jax.shard_map).parameters
-        knob = "check_vma" if "check_vma" in params else "check_rep"
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **{knob: False})
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -240,9 +228,19 @@ class ShardedPipeline:
             in_specs=(data_spec, row_spec),
             out_specs=(data_spec, row_spec, row_spec),
         )
-        return plan.tracked_jit(
+        jfn = plan.tracked_jit(
             f"striped.encode k{self.k}m{self.m} S{self.chunk_bytes}",
             shard)
+        if self._placement_one is None:
+            return jfn
+
+        def run(*args):
+            # the CRUSH program's int64 draws need x64 for this trace
+            # and call only (crush/kernel.py keeps it scoped)
+            with jax.enable_x64(True):
+                return jfn(*args)
+
+        return run
 
     def data_sharding(self) -> NamedSharding:
         return NamedSharding(

@@ -19,7 +19,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -67,7 +67,8 @@ def run_test(cmap: CrushMap, args: argparse.Namespace) -> int:
         print(f"rule {ruleno} ({rule.name}), x = {args.min_x}..{args.max_x},"
               f" numrep = {num_rep}..{num_rep}", file=sys.stderr)
 
-        results = _bulk_do_rule(cmap, ruleno, xs, num_rep, weights)
+        results, tier = _bulk_do_rule(cmap, ruleno, xs, num_rep, weights)
+        print(f"rule {ruleno} mapped by the {tier} tier", file=sys.stderr)
 
         per_device = np.zeros(cmap.max_devices, dtype=np.int64)
         sizes: Dict[int, int] = {}
@@ -116,8 +117,11 @@ def _fmt_vec(out: List[int]) -> str:
 
 
 def _bulk_do_rule(cmap: CrushMap, ruleno: int, xs: np.ndarray,
-                  num_rep: int, weights: List[int]) -> np.ndarray:
-    """All xs through one rule: TPU kernel when compilable, host otherwise."""
+                  num_rep: int, weights: List[int]
+                  ) -> Tuple[np.ndarray, str]:
+    """All xs through one rule: the device kernel when compilable, the
+    host mapper otherwise.  Returns the rows and the tier that served
+    them ("device" or "host")."""
     from ceph_tpu.ops import gf
 
     try:
@@ -127,7 +131,7 @@ def _bulk_do_rule(cmap: CrushMap, ruleno: int, xs: np.ndarray,
 
         run = ck.compile_rule(cmap, ruleno, result_max=num_rep,
                               weight=weights)
-        return run(xs)
+        return run(xs), "device"
     except NotImplementedError:
         rows = np.full((len(xs), num_rep), CRUSH_ITEM_NONE, dtype=np.int64)
         for i, x in enumerate(xs):
@@ -135,7 +139,7 @@ def _bulk_do_rule(cmap: CrushMap, ruleno: int, xs: np.ndarray,
                 cmap, ruleno, int(x), num_rep, weights)
             for j, v in enumerate(out[:num_rep]):
                 rows[i, j] = v
-        return rows
+        return rows, "host"
 
 
 def run(argv: List[str]) -> int:
@@ -189,6 +193,9 @@ def _write(path: Optional[str], content: str) -> None:
 
 
 def main() -> None:
+    from ceph_tpu.common import jaxcache
+
+    jaxcache.enable()
     sys.exit(run(sys.argv[1:]))
 
 

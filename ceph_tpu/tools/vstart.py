@@ -25,6 +25,9 @@ import time
 
 
 def _spawn(data_dir: str, tag: str, args, env_extra=None):
+    # every daemon runs JAX on the CPU unless the caller's environment
+    # names a platform: one chip serves one process, and a cluster is
+    # many processes.  The ready block prints each daemon's platform.
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
     env.update(env_extra or {})
@@ -32,6 +35,8 @@ def _spawn(data_dir: str, tag: str, args, env_extra=None):
     proc = subprocess.Popen([sys.executable, "-u", "-m", *args],
                             stdout=subprocess.PIPE, stderr=logf,
                             text=True, env=env)
+    proc.tag = tag
+    proc.jax_platforms = env["JAX_PLATFORMS"] or "default"
     return proc
 
 
@@ -238,6 +243,8 @@ def main(argv=None) -> int:
         print(f"export CEPH_TPU_SECRET={secret}")
     if rgw_addr:
         print(f"export CEPH_TPU_RGW=http://{rgw_addr}")
+    print("# daemon JAX platforms: " + " ".join(
+        f"{p.tag}={p.jax_platforms}" for p in procs))
     print(f"# stop: python -m ceph_tpu.tools.vstart"
           f" --data-dir {args.data_dir} --stop")
     return 0
