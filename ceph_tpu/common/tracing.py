@@ -22,7 +22,10 @@ pipeline seams emit `admission`, `queue.<class>`, `objlock`,
 `compute_op` (the scan op root), `subcompute osd.N` (per-peer
 hedged sub-compute flights) and `compute ...` (kernel evaluation /
 result-domain decode) — each workload class gets its own rows in
-the stage histograms.
+the stage histograms.  Beside the op trees, the encode service feeds
+each batched dispatch's seven `dispatch_*` stages (`Stages`) and each
+request's `encode_queue` wait, and the S3 gateway its requests'
+`s3.<METHOD>` self-time and `rados` waits.
 Inside a daemon the active span travels by contextvar, so nested sends
 (the primary's sub-ops fanned out under the op task) attach the right
 parent without threading a span through every call signature.
@@ -71,15 +74,24 @@ from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
-    "NULL_SPAN", "Span", "Tracer", "child_span", "child_span_sync",
-    "critical_path", "critical_path_spans", "current_span",
-    "env_enabled", "event", "stage_of", "start_child",
+    "NULL_SPAN", "Span", "Stages", "Tracer", "annotate", "child_span",
+    "child_span_sync", "critical_path", "critical_path_spans",
+    "current_dispatch", "current_span", "env_enabled", "event",
+    "stage_of", "start_child",
 ]
 
 # the span the running task is working under (primary op execution
 # sets it; sub-op sends read it) — context propagates per asyncio task
 current_span: contextvars.ContextVar[Optional["Span"]] = \
     contextvars.ContextVar("ceph_tpu_current_span", default=None)
+
+# the stage timeline of the batched device dispatch the running thread
+# works for: the encode service sets it around its off-loop hop
+# (asyncio.to_thread copies it to the worker); the plan's guarded call
+# hands it to the watchdog thread explicitly, since that thread keeps
+# no context of its own
+current_dispatch: contextvars.ContextVar[Optional["Stages"]] = \
+    contextvars.ContextVar("ceph_tpu_current_dispatch", default=None)
 
 #: per-trace span-tree bound: a runaway fan-out must not turn one op's
 #: trace into an unbounded buffer (overflow spans are counted, dropped)
@@ -428,6 +440,102 @@ def critical_path_spans(root: Span,
                      s.parent_id,
                      bool(s.attrs.get("cancelled"))))
     return _cp_reduce(recs, want_path)
+
+
+# ---------------------------------------------------------------------------
+# Consecutive stages across threads, and the profiler's clock
+# ---------------------------------------------------------------------------
+
+# lazily bound jax.profiler.TraceAnnotation (False where jax is absent:
+# the tracer must stay importable without it)
+_TraceAnnotation: Any = None
+
+
+def annotate(name: str):
+    """A host event `name` in the profiler's trace while a trace is
+    being taken (``jax.profiler``), a no-op context otherwise.  Keep it
+    to short synchronous stages: the trace reductions name a device
+    idle gap after the host event that overlaps it most, and a long
+    span would own every gap."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # pragma: no cover - no jax installed
+            TraceAnnotation = False
+        _TraceAnnotation = TraceAnnotation
+    if _TraceAnnotation and _TraceAnnotation.is_enabled():
+        return _TraceAnnotation(name)
+    return contextlib.nullcontext()
+
+
+class Stages:
+    """Consecutive stages of one root span, handed from thread to
+    thread.  Each ``mark(stage)`` ends the running stage and starts the
+    next as a child span of the root at the same instant, so the stages
+    divide the interval from the first mark to ``close()`` with no gap
+    and no overlap.  The hand-offs order the marks: a thread that hands
+    the work on blocks until it comes back.
+
+    ``annotated=True`` also opens the profiler annotation
+    ``ceph.<stage>`` (see `annotate`), closed by the next mark, which
+    must then come from the same thread.  ``rename=(old, new)`` renames
+    the running stage first when it is `old` (host work after a device
+    call turns out to precede another call)."""
+
+    __slots__ = ("root", "spans", "closed", "_ann", "_lock")
+
+    def __init__(self, root: Span):
+        self.root = root
+        self.spans: List[Span] = []
+        self.closed = False
+        self._ann: Optional[Tuple[Any, int]] = None
+        self._lock = threading.Lock()
+
+    def _end(self, now: float, rename: Optional[Tuple[str, str]]) -> None:
+        if self._ann is not None:
+            ann, owner = self._ann
+            self._ann = None
+            if owner == threading.get_ident():
+                ann.__exit__(None, None, None)
+        if self.spans:
+            cur = self.spans[-1]
+            if cur._end is None:
+                cur._end = now
+                cur.end = cur.start + (now - cur._t0)
+                if rename is not None and cur.name == rename[0]:
+                    cur.name = rename[1]
+
+    def mark(self, stage: str, annotated: bool = False,
+             rename: Optional[Tuple[str, str]] = None) -> None:
+        now = time.monotonic()
+        with self._lock:
+            if self.closed:
+                return
+            self._end(now, rename)
+            sp = self.root.child(stage)
+            sp._t0 = now
+            sp.start = self.root.start + (now - self.root._t0)
+            self.spans.append(sp)
+            if annotated:
+                ann = annotate(f"ceph.{stage}")
+                ann.__enter__()
+                self._ann = (ann, threading.get_ident())
+
+    def close(self) -> None:
+        now = time.monotonic()
+        with self._lock:
+            if not self.closed:
+                self.closed = True
+                self._end(now, None)
+
+    def stage_us(self) -> Dict[str, int]:
+        """Microseconds per stage name, summed over its spans: one
+        `Tracer.record_stages` sample."""
+        secs: Dict[str, float] = {}
+        for sp in self.spans:
+            secs[sp.name] = secs.get(sp.name, 0.0) + sp.duration_s
+        return {name: int(s * 1e6) for name, s in secs.items()}
 
 
 # ---------------------------------------------------------------------------

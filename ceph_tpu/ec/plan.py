@@ -46,10 +46,12 @@ is that layer:
   absolved, and the dispatch re-plans on the surviving set — the mesh
   shrinks, the batch never degrades to host because one chip died.
   Kill switch CEPH_TPU_MESH=0 (bit-identical single-device plans).
-* **Observability** — `stats()` exposes hit/miss/retrace counters and
-  per-plan dispatch counts/timings (plus the mesh section: healthy
-  set, dispatches, shrinks); bench.py and the erasure-code benchmark
-  CLI surface them.
+* **Observability** — `stats()` exposes hit/miss/retrace counters,
+  per-plan dispatch counts and the guarded calls' thread CPU against
+  wall time (plus the mesh section: healthy set, dispatches, shrinks);
+  bench.py and the erasure-code benchmark CLI surface them.  A guarded
+  call made for a batched dispatch marks its stages on that dispatch's
+  timeline (`tracing.Stages`): guard, launch, fetch, then the fold.
 
 Direct `jax.jit` on shape-polymorphic EC entry points is flagged by
 the `jit-bypass-plan` static-analysis rule; route new compiles through
@@ -59,6 +61,7 @@ the `jit-bypass-plan` static-analysis rule; route new compiles through
 from __future__ import annotations
 
 import os
+import re
 
 from ceph_tpu.common import flags
 import threading
@@ -99,13 +102,19 @@ __all__ = [
 _lock = threading.Lock()
 _plans = LruCache(cap=128)
 _mbits_cache = LruCache(cap=64)      # matrix signature -> device bit matrix
-_counters: Dict[str, int] = {"hits": 0, "misses": 0, "retraces": 0,
-                             "dispatches": 0, "host_fallbacks": 0,
-                             "oom_splits": 0, "quarantines": 0,
-                             "mesh_dispatches": 0, "mesh_rows": 0,
-                             "mesh_shrinks": 0, "mesh_probes": 0,
-                             "host_retirements": 0}
-_per_plan: Dict[str, Dict[str, float]] = {}
+# device_call_cpu_s / _wall_s: the watchdog thread's CPU time
+# (time.thread_time) and wall time over each guarded call's launch and
+# fetch — their ratio is the share of the call the thread ran, not
+# waiting on the device or the interpreter lock
+_counters: Dict[str, float] = {"hits": 0, "misses": 0, "retraces": 0,
+                               "dispatches": 0, "host_fallbacks": 0,
+                               "oom_splits": 0, "quarantines": 0,
+                               "mesh_dispatches": 0, "mesh_rows": 0,
+                               "mesh_shrinks": 0, "mesh_probes": 0,
+                               "host_retirements": 0,
+                               "device_call_cpu_s": 0.0,
+                               "device_call_wall_s": 0.0}
+_per_plan: Dict[str, Dict[str, object]] = {}
 _enabled = flags.enabled("CEPH_TPU_PLAN_CACHE")
 # poisoned-plan quarantine: a compiled callable that keeps failing is
 # evicted and its key blacklisted for a TTL (a single bad compile must
@@ -132,9 +141,9 @@ def stats() -> dict:
 
     hits/misses count plan-cache lookups; retraces counts actual XLA
     traces (each is one compile); per_plan maps plan labels to
-    dispatch counts, cumulative dispatch seconds (host-side
-    dispatch time — device completion is asynchronous) and the
-    executor that served them (e.g. ``pallas_words+crc``).
+    dispatch counts and the executor that served them (e.g.
+    ``pallas_words+crc``); device_call_cpu_s / device_call_wall_s sum
+    the guarded calls' launch + fetch on their own thread.
     """
     with _lock:
         out = {
@@ -179,31 +188,40 @@ def _note_retrace(label: str) -> None:
     with _lock:
         _counters["retraces"] += 1
         entry = _per_plan.setdefault(
-            label, {"dispatches": 0, "seconds": 0.0, "retraces": 0})
+            label, {"dispatches": 0, "retraces": 0})
         entry["retraces"] += 1
 
 
-def _note_dispatch(label: str, seconds: float, executor: str) -> None:
+def _note_dispatch(label: str, executor: str) -> None:
     with _lock:
         _counters["dispatches"] += 1
         entry = _per_plan.setdefault(
-            label, {"dispatches": 0, "seconds": 0.0, "retraces": 0})
+            label, {"dispatches": 0, "retraces": 0})
         entry["executor"] = executor
         entry["dispatches"] += 1
-        entry["seconds"] += seconds
+
+
+def _note_device_call(cpu_s: float, wall_s: float) -> None:
+    with _lock:
+        _counters["device_call_cpu_s"] += cpu_s
+        _counters["device_call_wall_s"] += wall_s
 
 
 def tracked_jit(label: str, fn: Callable, **jit_kwargs):
     """jax.jit with plan-cache observability: the wrapper body runs at
     trace time only, so the retrace counter increments exactly once
     per XLA compile.  All EC-path compiles must route through here (or
-    a plan kind) — the jit-bypass-plan lint rule enforces it."""
+    a plan kind) — the jit-bypass-plan lint rule enforces it.
+
+    The jitted function is named after the plan's kind (the label's
+    leading identifier, ``encode_crc`` for ``encode_crc[...] r2k8 ...``),
+    which names its programs and their ops in a profiler trace."""
 
     def traced(*args, **kwargs):
         _note_retrace(label)
         return fn(*args, **kwargs)
 
-    traced.__name__ = getattr(fn, "__name__", label)
+    traced.__name__ = re.match(r"\w*", label).group(0) or "plan"
     return jax.jit(traced, **jit_kwargs)
 
 
@@ -307,24 +325,28 @@ class ExecPlan:
 
     Mesh plans carry `sharding` (a NamedSharding over their device
     set) and `devices` (the participating chip ids, the device_call
-    attribution set); single-device plans leave both None/()."""
+    attribution set); single-device plans leave both None/().  `host`,
+    when set, turns the fetched host arrays into the plan's result (a
+    free view: the Pallas plan's words back to bytes), so calling the
+    plan launches and nothing more."""
 
     __slots__ = ("key", "label", "fn", "executor", "sharding",
-                 "devices")
+                 "devices", "host")
 
     def __init__(self, key: tuple, fn: Callable, executor: str,
-                 sharding=None, devices: Tuple[int, ...] = ()):
+                 sharding=None, devices: Tuple[int, ...] = (),
+                 host: Optional[Callable] = None):
         self.key = key
         self.label = _label(key)
         self.fn = fn
         self.executor = executor
         self.sharding = sharding
         self.devices = devices
+        self.host = host
 
     def __call__(self, *args):
-        t0 = time.perf_counter()
         out = self.fn(*args)
-        _note_dispatch(self.label, time.perf_counter() - t0, self.executor)
+        _note_dispatch(self.label, self.executor)
         return out
 
 
@@ -431,12 +453,37 @@ def _guarded(family: str, key: tuple, plan: ExecPlan, args: tuple,
     plan is fine) or falls through to the single-device plan (which
     owns its own accounting)."""
 
-    def run():
-        return _materialize(plan(*args))
+    # the batched dispatch this call serves, if any: the watchdog
+    # thread inherits no context, so its stages are marked through
+    # this reference
+    stages = tracing.current_dispatch.get()
 
+    def run():
+        cpu0, wall0 = time.thread_time(), time.perf_counter()
+        if stages is not None:
+            stages.mark("dispatch_launch", annotated=True)
+        try:
+            out = plan(*args)
+            if stages is not None:
+                stages.mark("dispatch_fetch", annotated=True)
+            out = _materialize(out)
+        finally:
+            if stages is not None:
+                # on this thread: it closes the annotation it opened
+                stages.mark("dispatch_guard")
+        _note_device_call(time.thread_time() - cpu0,
+                          time.perf_counter() - wall0)
+        return out if plan.host is None else plan.host(out)
+
+    if stages is not None:
+        # host work between two calls (an OOM halving) packs the next
+        stages.mark("dispatch_guard",
+                    rename=("dispatch_fold", "dispatch_pack"))
     status, out = circuit.device_call(
         family, run, batch=batch, label=plan.label,
         oom_to_fail=batch <= 1, devices=plan.devices or None)
+    if stages is not None:
+        stages.mark("dispatch_fold", annotated=True)
     if status == "ok":
         return "ok", out
     if status == "oom":
@@ -1473,10 +1520,13 @@ def _build_encode_crc_pallas(key: tuple, matrix: np.ndarray) -> ExecPlan:
                       lambda words: fused_encode_crc_words(coeffs, words))
 
     def run(_mbits, padded):
-        parity, crcs = jfn(gf_pallas.words_from_bytes(padded))
-        return gf_pallas.bytes_from_words(np.asarray(parity)), crcs
+        return jfn(gf_pallas.words_from_bytes(padded))
 
-    return ExecPlan(key, run, "pallas_words+crc")
+    def host(out):
+        parity, crcs = out
+        return gf_pallas.bytes_from_words(parity), crcs
+
+    return ExecPlan(key, run, "pallas_words+crc", host=host)
 
 
 def encode_with_crc(matrix: np.ndarray, data: np.ndarray,

@@ -141,6 +141,7 @@ if HAVE_JAX:
             out_shape=jax.ShapeDtypeStruct((n_tiles * _BT, 128),
                                            jnp.int32),
             interpret=interpret,
+            name="crc32c_words",
         ))
 
     def crc32c_blocks_words(words, length: int, init: int = 0xFFFFFFFF):

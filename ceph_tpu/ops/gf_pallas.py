@@ -202,6 +202,7 @@ if HAVE_JAX:
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((b, r, r4, 128), jnp.int32),
             interpret=interpret,
+            name="gf_words",
         ))
 
     @functools.lru_cache(maxsize=64)
@@ -220,6 +221,7 @@ if HAVE_JAX:
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((b, r, r4, 128), jnp.int32),
             interpret=interpret,
+            name="gf_words_smem",
         ))
 
     def _pick_ts(r4: int) -> int:

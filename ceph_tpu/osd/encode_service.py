@@ -93,17 +93,6 @@ def _buf(d):
         else bytes(d)
 
 
-_WAIT_EDGES_MS = (0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 50.0)
-
-
-def _wait_bucket(seconds: float) -> str:
-    ms = seconds * 1e3
-    for edge in _WAIT_EDGES_MS:
-        if ms <= edge:
-            return f"<={edge}ms"
-    return f">{_WAIT_EDGES_MS[-1]}ms"
-
-
 class _Req:
     __slots__ = ("fut", "payload", "nbytes", "t_q", "span_ctx")
 
@@ -148,10 +137,12 @@ class _Bucket:
         # two dispatch slots: the double buffer — batch N on device,
         # batch N+1 accumulating/launching behind it
         self.sem = asyncio.Semaphore(2)
+        # queue_seconds: each request's wait from enqueue to the start
+        # of its batch's dispatch, summed exactly
         self.stats: Dict[str, object] = {
             "requests": 0, "batches": 0, "dispatch_seconds": 0.0,
+            "queue_seconds": 0.0,
             "batch_size_hist": {}, "fill_pct_hist": {},
-            "wait_ms_hist": {},
         }
 
 
@@ -288,9 +279,10 @@ class EncodeService:
 
     def stats(self) -> dict:
         """Observability snapshot: aggregate counters, live queue
-        depth, and per-profile batch-size / fill-ratio / wait-time
-        histograms (the admin-socket `encode_service` command and the
-        bench contract line surface this)."""
+        depth, and per-profile batch-size / fill-ratio histograms with
+        the exact queue-wait and dispatch second sums (the
+        admin-socket `encode_service` command and the bench contract
+        line surface this)."""
         return {
             "enabled": self.enabled,
             **self.counters,
@@ -447,10 +439,7 @@ class EncodeService:
                         nbytes: int) -> None:
         async with q.sem:   # double buffer: at most 2 batches in flight
             t0 = time.perf_counter()
-            wait_hist = q.stats["wait_ms_hist"]
-            for r in batch:
-                b = _wait_bucket(t0 - r.t_q)
-                wait_hist[b] = wait_hist.get(b, 0) + 1
+            waits = [t0 - r.t_q for r in batch]
             # the batched device dispatch is ONE span serving N ops:
             # span LINKS carry the attribution (it parents none of
             # them — their own encode_wait spans cover the wall time)
@@ -463,7 +452,14 @@ class EncodeService:
                 flush_span.link(r.span_ctx)
             token = tracing.current_span.set(flush_span) \
                 if flush_span else None
+            # the dispatch's stages, as children of the flush span:
+            # handoff (loop -> worker), pack, guard, launch, fetch,
+            # fold, resume (worker -> loop); they divide dt
+            stages = tracing.Stages(flush_span) if flush_span else None
+            stoken = tracing.current_dispatch.set(stages)
             try:
+                if stages is not None:
+                    stages.mark("dispatch_handoff")
                 try:
                     outs = await asyncio.to_thread(
                         self._run_batch, q,
@@ -471,16 +467,29 @@ class EncodeService:
                 except BaseException as e:
                     self.counters["dispatch_errors"] += 1
                     outs = [e] * len(batch)
+                if stages is not None:
+                    stages.close()
                 dt = time.perf_counter() - t0
                 flush_span.set_attr("dispatch_ms", round(dt * 1e3, 3))
             finally:
+                tracing.current_dispatch.reset(stoken)
                 if token is not None:
                     tracing.current_span.reset(token)
+                if stages is not None:
+                    stages.close()      # no-op unless the hop raised
                 if self.tracer is not None:
                     self.tracer.finish(flush_span)
+            if stages is not None:
+                # the root's own self-time is not a stage: encode_flush
+                # never reaches the histograms
+                self.tracer.record_stages(stages.stage_us())
+                for w in waits:
+                    self.tracer.record_stages(
+                        {"encode_queue": int(w * 1e6)})
             self.counters["batches"] += 1
             q.stats["batches"] += 1             # type: ignore[operator]
             q.stats["dispatch_seconds"] += dt   # type: ignore[operator]
+            q.stats["queue_seconds"] += sum(waits)  # type: ignore[operator]
             sh = q.stats["batch_size_hist"]
             sk = str(_pow2_bucket(len(batch)))
             sh[sk] = sh.get(sk, 0) + 1
@@ -512,6 +521,11 @@ class EncodeService:
         from ceph_tpu.common import circuit
         from ceph_tpu.ec import plan as ec_plan
 
+        stages = tracing.current_dispatch.get()
+        if stages is not None:
+            # host work up to the device call; the plan marks the call
+            # itself and the fold after it (ec/plan._guarded)
+            stages.mark("dispatch_pack", annotated=True)
         # scoped to the EC families this batch can actually touch — an
         # unscoped delta would attribute a concurrent hitset/CRUSH
         # fault to this flush
@@ -546,6 +560,8 @@ class EncodeService:
             self.counters["device_fallback"] += 1
         if ec_plan.mesh_dispatches() > mesh_before:
             self.counters["mesh_batches"] += 1
+        if stages is not None:
+            stages.mark("dispatch_resume")
         return outs
 
     def _run_one(self, q: _Bucket, payload):

@@ -27,7 +27,7 @@ import hmac
 import logging
 import os
 
-from ceph_tpu.common import flags
+from ceph_tpu.common import flags, tracing
 import urllib.parse
 import xml.etree.ElementTree as ET
 from typing import Dict, List, Optional, Tuple
@@ -169,8 +169,6 @@ class S3Frontend:
         # operator turning bulk retention off must turn it off HERE —
         # an unsampled ingress leaves the OSDs to their own
         # osd_trace_sample_rate
-        from ceph_tpu.common import tracing
-
         try:
             rate = flags.flag_float(
                 "CEPH_TPU_RGW_TRACE_SAMPLE")
@@ -256,6 +254,14 @@ class S3Frontend:
                     status, rhdrs, rbody = await self._handle(
                         method.upper(), target, headers, body)
                     ingress.set_attr("status", status)
+                if ingress:
+                    # the request's critical path into the stage
+                    # histograms, as the OSDs feed theirs: the
+                    # gateway's own self-time (s3.<METHOD>) and its
+                    # waits on RADOS (rados)
+                    self.tracer.record_stages(
+                        tracing.critical_path_spans(ingress)[
+                            "stages"])
                 reason = {200: "OK", 204: "No Content",
                           206: "Partial Content", 400: "Bad Request",
                           403: "Forbidden", 404: "Not Found",

@@ -96,6 +96,47 @@ def test_64_concurrent_writes_bit_exact_with_few_dispatches(fused):
             assert bytes(shards[i]) == bytes(ws[i])
 
 
+DISPATCH_STAGES = ("dispatch_handoff", "dispatch_pack", "dispatch_guard",
+                   "dispatch_launch", "dispatch_fetch", "dispatch_fold",
+                   "dispatch_resume")
+
+
+def test_burst_records_the_seven_dispatch_stages(fused):
+    """Every batched dispatch feeds its seven stages to the tracer's
+    stage histograms; they divide dispatch_seconds (within 2 %), the
+    flush root's own self-time is no stage, and each request's queue
+    wait lands both in the exact queue_seconds sum and, one sample a
+    request, in the encode_queue histogram."""
+    from ceph_tpu.common.tracing import Tracer
+
+    codec = _codec()
+    sinfo = _sinfo()
+    bufs = [RNG.integers(0, 256, 64 << 10, dtype=np.uint8).tobytes()
+            for _ in range(24)]
+
+    async def main():
+        svc = EncodeService()
+        svc.tracer = Tracer("osd.test")
+        await asyncio.gather(*(svc.encode_with_hinfo(
+            sinfo, codec, b, range(6), logical_len=len(b)) for b in bufs))
+        await svc.stop()
+        return svc
+
+    svc = run(main())
+    (prof,) = svc.stats()["profiles"].values()
+    hist = svc.tracer.stage_hist
+    assert set(DISPATCH_STAGES) <= set(hist), sorted(hist)
+    assert not any(s.startswith("encode_flush") for s in hist)
+    for s in DISPATCH_STAGES:
+        assert hist[s].count == prof["batches"], s
+    total = sum(hist[s].total for s in DISPATCH_STAGES)
+    assert total == pytest.approx(prof["dispatch_seconds"], rel=0.02)
+    assert hist["encode_queue"].count == prof["requests"] == 24
+    assert hist["encode_queue"].total == pytest.approx(
+        prof["queue_seconds"], rel=0.02, abs=24e-6)
+    assert "wait_ms_hist" not in prof
+
+
 def test_encode_and_decode_kinds_batch_and_match(fused):
     """Plain-encode (the RMW/recovery re-encode kind) and decode (the
     recovery/read kind) both batch and stay bit-exact."""

@@ -137,6 +137,41 @@ def test_s3_http_object_lifecycle():
     asyncio.run(asyncio.wait_for(run(), 120))
 
 
+def test_s3_put_feeds_the_gateway_stage_sums():
+    """One PUT's critical path reaches the gateway's stage histograms:
+    its own self-time as ``s3.PUT`` and its waits on RADOS as
+    ``rados``, which together span the request."""
+    async def run():
+        cluster = Cluster(num_osds=3, osds_per_host=1)
+        await cluster.start()
+        fe = None
+        try:
+            fe, addr = await _stack(cluster)
+            s3 = MiniS3(addr)
+            st, _, _ = await s3.request("PUT", "/logs")
+            assert st == 200
+            before = {s: h.total for s, h in fe.tracer.stage_hist.items()}
+            st, _, _ = await s3.request("PUT", "/logs/day.txt",
+                                        body=b"z" * 100_000)
+            assert st == 200
+            hist = fe.tracer.stage_hist
+            put = hist["s3.PUT"].total - before["s3.PUT"]
+            rados = hist["rados"].total - before.get("rados", 0.0)
+            assert put > 0 and rados > 0
+            ingress = [s for s in fe.tracer.dump()
+                       if s["name"] == "s3.PUT /logs/day.txt"]
+            assert ingress
+            assert put + rados == pytest.approx(
+                ingress[-1]["duration_us"] / 1e6, rel=0.05, abs=1e-4)
+            await s3.close()
+        finally:
+            if fe is not None:
+                await fe.stop()
+            await cluster.stop()
+
+    asyncio.run(asyncio.wait_for(run(), 120))
+
+
 def test_s3_http_multipart_round_trip():
     async def run():
         cluster = Cluster(num_osds=3, osds_per_host=1)

@@ -28,9 +28,54 @@ EC22 = {"plugin": "ec_jax", "technique": "reed_sol_van",
         "k": "2", "m": "2", "crush-failure-domain": "osd"}
 
 
+class _FullDraw:
+    """A messenger delay source that always draws the whole injected
+    delay (the stock one draws uniformly below it)."""
+
+    def random(self):
+        return 1.0
+
+
 def _span(sid, parent, name, t0, dur, **attrs):
     return {"span_id": sid, "parent_id": parent, "name": name,
             "t0_us": t0, "duration_us": dur, "attrs": attrs}
+
+
+def test_stages_divide_the_interval_across_threads():
+    """Marks from several threads make consecutive child spans of the
+    root with no gap or overlap; a renamed stage keeps its time under
+    its new name; marks after close are dropped; with no profiler
+    running an annotated stage opens nothing."""
+    import threading
+
+    root = Tracer("svc").start("encode_flush x")
+    st = tracing.Stages(root)
+    st.mark("dispatch_handoff")
+    t = threading.Thread(target=lambda: (
+        st.mark("dispatch_pack", annotated=True),
+        st.mark("dispatch_guard"),
+        st.mark("dispatch_fold", annotated=True),
+        st.mark("dispatch_guard", rename=("dispatch_fold",
+                                          "dispatch_pack")),
+        st.mark("dispatch_fold", annotated=True),
+        st.mark("dispatch_resume")))
+    t.start()
+    t.join(10)
+    assert not t.is_alive()
+    st.close()
+    st.mark("dispatch_late")
+    names = [sp.name for sp in st.spans]
+    assert names == ["dispatch_handoff", "dispatch_pack", "dispatch_guard",
+                     "dispatch_pack", "dispatch_guard", "dispatch_fold",
+                     "dispatch_resume"]
+    for a, b in zip(st.spans, st.spans[1:]):
+        assert a._end == b._t0
+    assert all(sp.parent_id == root.span_id for sp in st.spans)
+    us = st.stage_us()
+    assert set(us) == set(names)
+    span_us = (st.spans[-1]._end - st.spans[0]._t0) * 1e6
+    assert sum(us.values()) == pytest.approx(span_us, abs=len(us))
+    assert tracing.annotate("ceph.x").__class__.__name__ == "nullcontext"
 
 
 def test_tracer_unit():
@@ -457,10 +502,15 @@ def test_tail_exemplar_attributes_straggler_subread():
             cluster.client.trace_all = True
             # STAGGERED delays: identical delays can complete in one
             # event-loop wave, leaving no straggler in flight to
-            # cancel — one peer must win, the rest must be cut loose
+            # cancel — one peer must win, the rest must be cut loose.
+            # The messenger draws each delay uniformly below the knob;
+            # a full draw holds every peer at its knob, since a draw
+            # near zero answers under the complaint time and the read
+            # is then no tail op at all
             for i, o in enumerate(slow_peers):
                 cluster.osds[o].msgr.inject_internal_delays = \
                     0.15 + 0.1 * i
+                cluster.osds[o].msgr._inject_rng = _FullDraw()
             try:
                 got = await io.read(oid)
             finally:
