@@ -1536,7 +1536,8 @@ def encode_with_crc(matrix: np.ndarray, data: np.ndarray,
 
     crcs are ZERO-seeded per-chunk crc32c (seed advances are host
     scalars: crc32c(init, chunk) = crc32c_zeros(init, S) ^ crc0);
-    callers fold them into cumulative HashInfo ledgers.  Returns None
+    callers fold them into cumulative HashInfo ledgers with
+    checksum.crc32c_fold_ledger.  Returns None
     when no jax backend is available.
     """
     if not (HAVE_JAX and gf.backend_available()):

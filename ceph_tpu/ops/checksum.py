@@ -162,6 +162,85 @@ def crc32c_combine(crc_a: int, crc_b: int, len_b: int) -> int:
     return crc32c_zeros(crc_a, len_b) ^ crc_b
 
 
+# most rows one level of crc32c_fold_ledger combines (its tables take
+# 4 KiB a row): an object of up to 255 stripes folds in one level
+_FOLD_RADIX = 256
+
+_BYTE_BITS = (np.arange(256)[:, None] >> np.arange(8)) & 1    # (256, 8)
+
+
+def _byte_tables(images: np.ndarray) -> np.ndarray:
+    """(..., 32) images of the bits 1 << b under a GF(2)-linear map ->
+    (..., 4, 256) byte-sliced tables: entry [j, x] is the image of
+    x << 8j."""
+    basis = images.reshape(*images.shape[:-1], 4, 1, 8)
+    return np.bitwise_xor.reduce(
+        np.where(_BYTE_BITS == 1, basis, np.uint32(0)), axis=-1)
+
+
+def _apply_tables(tables: np.ndarray, v: np.ndarray) -> np.ndarray:
+    """The map of (4, 256) byte-sliced `tables`, applied to uint32 `v`."""
+    return (tables[0][v & 0xFF] ^ tables[1][(v >> 8) & 0xFF]
+            ^ tables[2][(v >> 16) & 0xFF] ^ tables[3][v >> 24])
+
+
+@functools.lru_cache(maxsize=16)
+def _zero_advance_tables(length: int, radix: int) -> np.ndarray:
+    """(radix, 4, 256) uint32 byte-sliced tables: entry [p, j, x] is
+    x << 8j advanced through (radix - 1 - p) * length zero bytes.
+
+    The advance is linear over GF(2).  A cold build makes 32
+    crc32c_zeros calls, the basis images of one `length` step, and
+    doubles from there in numpy: steps^K applied to the images of
+    steps^0..K-1 gives those of steps^K..2K-1.  An entry is one
+    (length, radix) pair in use: objects of up to 255 stripes of one
+    chunk size use at most eight."""
+    step = np.array([crc32c_zeros(1 << b, length) for b in range(32)],
+                    dtype=np.uint32)
+    powers = (np.uint32(1) << np.arange(32, dtype=np.uint32))[None]
+    while len(powers) < radix:               # radix is a power of two
+        tables = _byte_tables(step)
+        powers = np.concatenate([powers, _apply_tables(tables, powers)])
+        step = _apply_tables(tables, step)
+    return _byte_tables(powers[::-1])
+
+
+def crc32c_fold_ledger(crc0, chunk: int, init: int) -> np.ndarray:
+    """Cumulative crc32c of each column's chunks, stripe after stripe.
+
+    `crc0` is an (S, n) array of zero-seeded crc32c of `chunk`-byte
+    chunks; column i's result is crc32c(init, chunk_0 || ... ||
+    chunk_{S-1}) as uint32[n].  Combining is associative (crc(A||B) =
+    Z_len(B)(crc A) ^ crc B), so the rows fold as a tree whose levels
+    combine up to _FOLD_RADIX rows: each level front-pads with zero
+    rows to whole groups (Z(0) = 0) and folds every group of every
+    column at once, one table lookup per byte of every row and one XOR
+    reduction.  The seed is one more row in front of the stripes, which
+    the tree advances past all of them.  Each numpy or native call may
+    give up the interpreter lock, which a busy process hands back only
+    after a switch interval, so once its tables are cached the fold
+    makes a few numpy calls per level, none per row, and no
+    crc32c_zeros call."""
+    crc0 = np.asarray(crc0, dtype="<u4")
+    n = crc0.shape[1]
+    rows = np.concatenate(
+        [np.full((1, n), init & 0xFFFFFFFF, "<u4"), crc0])
+    lane = np.arange(4)
+    length = chunk
+    while len(rows) > 1:
+        radix = min(1 << (len(rows) - 1).bit_length(), _FOLD_RADIX)
+        lead = -len(rows) % radix
+        if lead:
+            rows = np.concatenate([np.zeros((lead, n), "<u4"), rows])
+        lanes = rows.view(np.uint8).reshape(-1, radix, n, 4)
+        pos = np.arange(radix)[:, None, None]
+        rows = np.bitwise_xor.reduce(
+            _zero_advance_tables(length, radix)[pos, lane, lanes],
+            axis=(1, 3))
+        length *= radix
+    return rows[0]
+
+
 def crc32c_blocks(data, block_size: int, init: int = 0xFFFFFFFF) -> np.ndarray:
     """Per-block crc32c over uniform blocks (host loop, native inner)."""
     arr = _np_u8(data)

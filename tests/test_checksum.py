@@ -71,6 +71,73 @@ class TestCrc32cHost:
             assert vals[i] == cks.crc32c(0xFFFFFFFF, buf[i * 512:(i + 1) * 512])
 
 
+def _ledger_loop(crc0, chunk, init):
+    """The scalar reference: fold each column's stripes one by one."""
+    out = []
+    for i in range(crc0.shape[1]):
+        c = init
+        for s in range(crc0.shape[0]):
+            c = cks.crc32c_zeros(c, chunk) ^ int(crc0[s, i])
+        out.append(c)
+    return out
+
+
+@pytest.mark.parametrize("init", [0, 0xFFFFFFFF])
+@pytest.mark.parametrize("chunk", [512, 1000, 4096])
+@pytest.mark.parametrize("n", [3, 10, 14])
+@pytest.mark.parametrize("stripes", [1, 2, 3, 7, 128, 129, 600])
+def test_fold_ledger_matches_scalar_loop(stripes, n, chunk, init):
+    rng = np.random.default_rng(1009 * stripes + 31 * n + chunk)
+    crc0 = rng.integers(0, 1 << 32, (stripes, n), dtype=np.uint32)
+    got = cks.crc32c_fold_ledger(crc0, chunk, init)
+    assert got.dtype == np.uint32 and got.shape == (n,)
+    assert got.tolist() == _ledger_loop(crc0, chunk, init)
+
+
+def test_fold_ledger_without_native_library(monkeypatch):
+    """Tables built through the pure-Python crc32c_zeros give the same
+    ledger, and the ledger is the crc32c of each column's bytes."""
+    rng = np.random.default_rng(29)
+    stripes, n, chunk = 129, 10, 1000
+    data = rng.integers(0, 256, (stripes, n, chunk), dtype=np.uint8)
+    crc0 = np.array([[cks.crc32c(0, data[s, i]) for i in range(n)]
+                     for s in range(stripes)], dtype=np.uint32)
+    want = _ledger_loop(crc0, chunk, 0xFFFFFFFF)
+    assert want == [cks.crc32c(0xFFFFFFFF, data[:, i].tobytes())
+                    for i in range(n)]
+    cks._zero_advance_tables.cache_clear()
+    monkeypatch.setattr(native, "get_lib", lambda: None)
+    try:
+        got = cks.crc32c_fold_ledger(crc0, chunk, 0xFFFFFFFF)
+    finally:
+        cks._zero_advance_tables.cache_clear()
+    assert got.tolist() == want
+
+
+def test_fold_ledger_cold_tables_cost_one_step_of_native_calls(
+        monkeypatch):
+    """A cold table build makes 32 crc32c_zeros calls, whatever the
+    radix: further steps are numpy products, not native calls."""
+    calls = []
+    zeros = cks.crc32c_zeros
+
+    def counted(crc, length):
+        calls.append(length)
+        return zeros(crc, length)
+
+    cks._zero_advance_tables.cache_clear()
+    monkeypatch.setattr(cks, "crc32c_zeros", counted)
+    try:
+        tables = cks._zero_advance_tables(4096, 256)
+    finally:
+        cks._zero_advance_tables.cache_clear()
+    assert calls == [4096] * 32
+    assert tables.shape == (256, 4, 256)
+    for p in (0, 1, 100, 255):
+        for x in (1, 0x80, 0xFF):
+            assert int(tables[p, 2, x]) == zeros(x << 16, (255 - p) * 4096)
+
+
 class TestXxhash:
     def test_xxh32_empty(self):
         assert cks.xxh32(b"", 0) == 0x02CC5D05

@@ -314,6 +314,46 @@ def test_encode_with_hinfo_fused_device_tier(monkeypatch):
                              memoryview(data)[:len(data) - 17])
 
 
+def test_fused_result_folds_ledger_without_per_stripe_calls(monkeypatch):
+    """The hinfo ledger of a 128-stripe, 10-shard object makes no scalar
+    crc32c_zeros call once the advance tables are cached: the call
+    count must not grow with stripes x shards."""
+    from ceph_tpu.osd import ec_util
+
+    class Codec:
+        def get_chunk_count(self):
+            return 10
+
+    stripes, k, chunk = 128, 8, 512
+    sinfo = ec_util.StripeInfo(k, k * chunk)
+    src = RNG.integers(0, 256, stripes * k * chunk, dtype=np.uint8)
+    arr = src.reshape(stripes, k, chunk)
+    parity = RNG.integers(0, 256, (stripes, 2, chunk), dtype=np.uint8)
+    crc0 = RNG.integers(0, 1 << 32, (stripes, 10), dtype=np.uint32)
+    ec_util._fused_result(sinfo, Codec(), src, arr, parity, crc0,
+                          range(10), None, None)        # warm the tables
+    calls = []
+    zeros = cks.crc32c_zeros
+
+    def counted(crc, length):
+        calls.append(length)
+        return zeros(crc, length)
+
+    monkeypatch.setattr(cks, "crc32c_zeros", counted)
+    _, hinfo, _ = ec_util._fused_result(sinfo, Codec(), src, arr, parity,
+                                        crc0, range(10), None, None)
+    assert not calls, f"{len(calls)} crc32c_zeros calls per object"
+    monkeypatch.undo()
+    want = []
+    for i in range(10):
+        c = 0xFFFFFFFF
+        for s in range(stripes):
+            c = cks.crc32c_zeros(c, chunk) ^ int(crc0[s, i])
+        want.append(c)
+    assert hinfo.cumulative_shard_hashes == want
+    assert hinfo.total_chunk_size == stripes * chunk
+
+
 # -- observability + the acceptance bound ----------------------------------
 
 
