@@ -105,7 +105,9 @@ _mbits_cache = LruCache(cap=64)      # matrix signature -> device bit matrix
 # device_call_cpu_s / _wall_s: the watchdog thread's CPU time
 # (time.thread_time) and wall time over each guarded call's launch and
 # fetch — their ratio is the share of the call the thread ran, not
-# waiting on the device or the interpreter lock
+# waiting on the device or the interpreter lock.  codec_compile_s /
+# codec_compiles: the `codec_compile` stages, the lowering and compile of
+# the fused plan's specialised kernel for a registered matrix
 _counters: Dict[str, float] = {"hits": 0, "misses": 0, "retraces": 0,
                                "dispatches": 0, "host_fallbacks": 0,
                                "oom_splits": 0, "quarantines": 0,
@@ -113,8 +115,13 @@ _counters: Dict[str, float] = {"hits": 0, "misses": 0, "retraces": 0,
                                "mesh_shrinks": 0, "mesh_probes": 0,
                                "host_retirements": 0,
                                "device_call_cpu_s": 0.0,
-                               "device_call_wall_s": 0.0}
+                               "device_call_wall_s": 0.0,
+                               "codec_compile_s": 0.0,
+                               "codec_compiles": 0}
 _per_plan: Dict[str, Dict[str, object]] = {}
+# plan label -> the XOR network of its specialised kernel, written once
+# as the plan is built; a property of the plan, so reset_stats keeps it
+_networks: Dict[str, Dict[str, int]] = {}
 _enabled = flags.enabled("CEPH_TPU_PLAN_CACHE")
 # poisoned-plan quarantine: a compiled callable that keeps failing is
 # evicted and its key blacklisted for a TTL (a single bad compile must
@@ -142,8 +149,11 @@ def stats() -> dict:
     hits/misses count plan-cache lookups; retraces counts actual XLA
     traces (each is one compile); per_plan maps plan labels to
     dispatch counts and the executor that served them (e.g.
-    ``pallas_words+crc``); device_call_cpu_s / device_call_wall_s sum
-    the guarded calls' launch + fetch on their own thread.
+    ``pallas_words+crc``); a fused plan of a registered matrix adds its
+    XOR network (``parity_rows``, ``xor_terms``) and its
+    ``codec_compile_s``.  device_call_cpu_s / device_call_wall_s sum
+    the guarded calls' launch + fetch on their own thread;
+    codec_compile_s / codec_compiles the `codec_compile` stages.
     """
     with _lock:
         out = {
@@ -151,7 +161,8 @@ def stats() -> dict:
             "plans": len(_plans),
             "quarantined_plans": len(_quarantine),
             "enabled": _enabled,
-            "per_plan": {k: dict(v) for k, v in _per_plan.items()},
+            "per_plan": {k: {**v, **_networks.get(k, {})}
+                         for k, v in _per_plan.items()},
         }
     # breaker states + trip/probe/fallback counters ride the same
     # snapshot (the device_health admin command and bench read this)
@@ -178,6 +189,7 @@ def clear() -> None:
     """Drop every cached plan (tests; production never needs this)."""
     with _lock:
         _plans.clear()
+        _networks.clear()
         _mbits_cache.clear()
         _quarantine.clear()
         _plan_failures.clear()
@@ -199,6 +211,16 @@ def _note_dispatch(label: str, executor: str) -> None:
             label, {"dispatches": 0, "retraces": 0})
         entry["executor"] = executor
         entry["dispatches"] += 1
+
+
+def _note_codec_compile(label: str, secs: float) -> None:
+    with _lock:
+        _counters["codec_compile_s"] += secs
+        _counters["codec_compiles"] += 1
+        entry = _per_plan.setdefault(
+            label, {"dispatches": 0, "retraces": 0})
+        entry["codec_compile_s"] = \
+            float(entry.get("codec_compile_s", 0.0)) + secs
 
 
 def _note_device_call(cpu_s: float, wall_s: float) -> None:
@@ -462,12 +484,15 @@ def _guarded(family: str, key: tuple, plan: ExecPlan, args: tuple,
         cpu0, wall0 = time.thread_time(), time.perf_counter()
         if stages is not None:
             stages.mark("dispatch_launch", annotated=True)
+        # a plan's first launch may mark a stage of its own
+        token = tracing.current_dispatch.set(stages)
         try:
             out = plan(*args)
             if stages is not None:
                 stages.mark("dispatch_fetch", annotated=True)
             out = _materialize(out)
         finally:
+            tracing.current_dispatch.reset(token)
             if stages is not None:
                 # on this thread: it closes the annotation it opened
                 stages.mark("dispatch_guard")
@@ -1510,17 +1535,50 @@ def fused_encode_crc_words(coeffs: np.ndarray, words):
     return parity, crcs.reshape(b, n)
 
 
+def _codec_compile(label: str, jfn, words) -> None:
+    """The `codec_compile` stage: the first launch of a registered
+    matrix's fused plan lowers and compiles its specialised kernel here
+    (the launch then finds it in jit's cache), inside the launch's
+    guard (watchdog and breaker, as any first compile), timed on its
+    own into `stats()` and marked on the timeline of the batched
+    dispatch it serves.  A compile that fails raises to the guard."""
+    stages = tracing.current_dispatch.get()
+    if stages is not None:
+        stages.mark("codec_compile")
+    t0 = time.perf_counter()
+    try:
+        with tracing.annotate("ceph.codec_compile"):
+            jfn.lower(words).compile()
+    finally:
+        _note_codec_compile(label, time.perf_counter() - t0)
+        if stages is not None:
+            stages.mark("dispatch_launch", annotated=True)
+
+
 def _build_encode_crc_pallas(key: tuple, matrix: np.ndarray) -> ExecPlan:
     """The fused plan over the word view of the host bytes (free both
-    ways: gf_pallas.words_from_bytes / bytes_from_words)."""
+    ways: gf_pallas.words_from_bytes / bytes_from_words).  For a
+    registered matrix (the specialised kernel) the plan records the
+    kernel's XOR network into `stats()` as it is built, and its first
+    launch compiles in the `codec_compile` stage."""
     from ceph_tpu.ops import gf_pallas
 
     coeffs = np.array(matrix, dtype=np.uint8)
-    jfn = tracked_jit(_label(key),
+    label = _label(key)
+    jfn = tracked_jit(label,
                       lambda words: fused_encode_crc_words(coeffs, words))
+    network = gf_pallas.registered(coeffs)
+    if network is not None:
+        with _lock:
+            _networks[label] = dict(network)
+    uncompiled = [network is not None]
 
     def run(_mbits, padded):
-        return jfn(gf_pallas.words_from_bytes(padded))
+        words = gf_pallas.words_from_bytes(padded)
+        if uncompiled[0]:
+            _codec_compile(label, jfn, words)
+            uncompiled[0] = False
+        return jfn(words)
 
     def host(out):
         parity, crcs = out

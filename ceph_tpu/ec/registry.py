@@ -146,6 +146,10 @@ def _register_builtin(reg: ErasureCodePluginRegistry) -> None:
     # compatibility aliases so reference profiles
     # (plugin=jerasure technique=reed_sol_van k=2 m=2 — the
     # osd_pool_default_erasure_code_profile) resolve to the TPU codec.
+    # `isa` thereby takes jerasure's reed_sol_van matrix; Ceph's ISA
+    # plugin builds its own with ISA-L's gf_gen_rs_matrix, so an isa
+    # pool's parity rows after the first are not ISA-L's (an open
+    # question).
     for name in ("ec_jax", "jerasure", "isa"):
         reg.add(name, ErasureCodePlugin(name, _make_jax_factory("reed_sol_van")))
 

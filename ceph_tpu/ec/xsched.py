@@ -260,7 +260,7 @@ def prefer_schedule(sched: XorSchedule) -> bool:
     op count: a schedule wins when it is small enough to unroll AND
     saves at least the configured fraction of the naive XOR count.
     Sparse bitmatrix programs qualify; dense GF(2^8) bit expansions
-    (e.g. reed_sol k8m3: hundreds of surviving ops) keep the MXU
+    (e.g. reed_sol_van k8m4: 323 surviving ops) keep the MXU
     matmul."""
     if not enabled() or sched.xors_naive <= 0:
         return False

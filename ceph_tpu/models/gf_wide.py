@@ -206,9 +206,11 @@ def decode_matrix_w(coding: np.ndarray, k: int, erasures: list,
 
 def reed_sol_van_matrix_w(k: int, m: int, w: int) -> np.ndarray:
     """The jerasure reed_sol_van construction over GF(2^w) (the w=8
-    path in models/reed_solomon.py generalized to wide words): extended
-    Vandermonde -> systematize by column ops -> scale coding columns so
-    the first coding row is all ones."""
+    path in models/reed_solomon.py generalized to wide words), as
+    reed_sol.c's reed_sol_big_vandermonde_distribution_matrix builds it:
+    extended Vandermonde -> systematize by column ops -> scale coding
+    columns so the first coding row is all ones -> scale each later
+    coding row so that it starts with one."""
     f = Field(w)
     rows, cols = k + m, k
     v = np.zeros((rows, cols), dtype=np.uint64)
@@ -244,5 +246,11 @@ def reed_sol_van_matrix_w(k: int, m: int, w: int) -> np.ndarray:
         if coding[0, j] not in (0, 1):
             c = f.inv(int(coding[0, j]))
             for r in range(m):
+                coding[r, j] = f.mul(int(coding[r, j]), c)
+    # scale each later coding row so its first element is one
+    for r in range(1, m):
+        if coding[r, 0] not in (0, 1):
+            c = f.inv(int(coding[r, 0]))
+            for j in range(k):
                 coding[r, j] = f.mul(int(coding[r, j]), c)
     return coding.astype(f.dtype)

@@ -7,8 +7,9 @@ technique=reed_sol_van / reed_sol_r6_op / cauchy_orig at w=8
 (/root/reference/src/erasure-code/jerasure/ErasureCodeJerasure.cc:200-204,
 :252-255, :327).  Implementation is original, written from the algorithm (extended
 Vandermonde -> systematic by column ops -> coding columns scaled so the
-first coding row is all ones); the single Field-parameterized copy
-lives in models/gf_wide.py and serves w in {8, 16, 32}.
+first coding row is all ones -> each later coding row scaled so that it
+starts with one, reed_sol.c's last step); the single Field-parameterized
+copy lives in models/gf_wide.py and serves w in {8, 16, 32}.
 """
 
 from __future__ import annotations

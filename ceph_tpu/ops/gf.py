@@ -20,7 +20,9 @@ algebra onto it by *bit-decomposition*:
 
 Field: GF(2^8) with primitive polynomial 0x11d and generator x (= 2), the
 same field jerasure/gf-complete and isa-l use for w=8, so encoded chunks are
-bit-identical with the reference's `reed_sol_van` output.
+bit-identical with the reference's `reed_sol_van` output (given jerasure's
+matrix, each coding row after the first scaled to start with one: see
+models/gf_wide.reed_sol_van_matrix_w).
 
 Host-side (numpy) mirrors of each op serve as the independent reference
 implementation for tests and for small/latency-sensitive calls.

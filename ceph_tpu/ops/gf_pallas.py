@@ -68,7 +68,8 @@ _MFE = int(0xFEFEFEFE) - (1 << 32)
 
 # Encode matrices registered by codecs: these (and only these) get the
 # unrolled specialized kernel; everything else uses the generic one.
-_registered: set = set()
+# Each maps to the size of its unrolled XOR network (`register_matrix`).
+_registered: dict = {}
 
 # Test hook: force interpret-mode pallas (runs on CPU) regardless of
 # platform, so the kernel logic is exercised in the CPU test tier.
@@ -83,9 +84,22 @@ def _coeff_key(matrix: np.ndarray) -> tuple:
 def register_matrix(matrix: np.ndarray) -> None:
     """Mark a generator matrix as hot: it will be compiled into the
     specialized unrolled kernel on first use (compile cost amortized
-    across the lifetime of the codec)."""
-    if len(_registered) < 64:
-        _registered.add(_coeff_key(matrix))
+    across the lifetime of the codec).  Records the kernel's XOR
+    network: ``parity_rows`` output rows and ``xor_terms``, the set
+    bits of the coefficients (one XOR of a ladder power each)."""
+    key = _coeff_key(matrix)
+    if key not in _registered and len(_registered) < 64:
+        _registered[key] = {
+            "parity_rows": len(key),
+            "xor_terms": sum(bin(c).count("1") for row in key
+                             for c in row)}
+
+
+def registered(matrix: np.ndarray):
+    """The XOR network ``register_matrix`` recorded for this matrix
+    (a dict of ``parity_rows`` and ``xor_terms``), or None where the
+    matrix is not registered and takes the generic kernel."""
+    return _registered.get(_coeff_key(matrix))
 
 
 def words_from_bytes(data: np.ndarray) -> np.ndarray:

@@ -180,6 +180,48 @@ def _hinfo_chunk_ok(at: Dict[str, bytes], shard: int,
     return cks.crc32c(0xFFFFFFFF, payload) == hi.get_chunk_hash(shard)
 
 
+def _hinfo_of_rebuilt(codec, at: Dict[str, bytes],
+                      payload: Dict[int, bytes]
+                      ) -> Optional[Dict[str, bytes]]:
+    """The attrs a decode-and-re-encode recovery installs with its
+    rebuilt streams (`payload`, every shard of the object), or None
+    where the rebuild must not be installed.
+
+    The data shards' streams and the first coding shard's must match
+    the source's hinfo ledger: they show that the decode and the
+    re-encode gave back the acknowledged bytes, and a decode through a
+    faulty device or through a parity row another coding matrix made
+    fails them.  Only then do the later coding shards' entries come
+    from their rebuilt streams: reed_sol_van objects stored before its
+    coding rows after the first were scaled as jerasure scales them
+    hold other bytes, and a ledger of them, in those shards.  A ledger
+    without chunk hashes (RMW-era objects) is kept as it is; a
+    consistent object's comes back unchanged."""
+    try:
+        hi = ec_util.HashInfo.from_dict(json.loads(at[HINFO_ATTR]))
+    except (KeyError, ValueError):
+        return at
+    if not hi.has_chunk_hash():
+        return at
+    k, n = codec.get_data_chunk_count(), codec.get_chunk_count()
+    if len(hi.cumulative_shard_hashes) != n:
+        return None
+    later = {}
+    for i in range(n):
+        s = codec.chunk_index(i)
+        crc = cks.crc32c(0xFFFFFFFF, payload[s])
+        if crc == hi.get_chunk_hash(s):
+            continue
+        if i <= k:
+            return None
+        later[s] = crc
+    if not later:
+        return at
+    for s, crc in later.items():
+        hi.cumulative_shard_hashes[s] = crc
+    return {**at, HINFO_ATTR: json.dumps(hi.to_dict()).encode()}
+
+
 class _SkipApply(Exception):
     """Internal: a sub-write adjudicated as a superseded straggler —
     ack success without applying."""
@@ -468,7 +510,11 @@ class OSDDaemon:
                      # the classic k-read reconstruct
                      "repair_fragments": 0,
                      "repair_objects": 0,
-                     "repair_fallbacks": 0}
+                     "repair_fallbacks": 0,
+                     # decoded objects whose rebuilt data (or first
+                     # coding shard) failed the source's crc ledger and
+                     # were not installed
+                     "recover_ledger_refusals": 0}
         # async micro-batching encode/decode front end: concurrent EC
         # ops share plan-cached device dispatches; inline (pre-service
         # behavior) when the device tier is absent or
@@ -3813,6 +3859,17 @@ class OSDDaemon:
                     log.exception("osd.%d: re-encode of %s failed",
                                   self.osd_id, p["oid"])
             done = done2
+        attrs = await asyncio.to_thread(
+            lambda: [_hinfo_of_rebuilt(codec, p["attrs"], p["payload"])
+                     for p in done])
+        for p, at in zip(done, attrs):
+            if at is None:
+                self.perf["recover_ledger_refusals"] += 1
+                log.error("osd.%d: rebuilt shards of %s fail their crc"
+                          " ledger: not installed", self.osd_id, p["oid"])
+            else:
+                p["attrs"] = at
+        done = [p for p, at in zip(done, attrs) if at is not None]
         self.tracer.record_stages(
             {"recover_decode": int((time.monotonic() - t_dec) * 1e6)})
         return done + done_repair

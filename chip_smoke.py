@@ -372,6 +372,7 @@ def placement_phase(size: Size, clock: CompileClock) -> None:
 def setup(rehearsal: bool) -> None:
     from ceph_tpu import native
     from ceph_tpu.common import circuit, jaxcache
+    from ceph_tpu.ec import plan
     from ceph_tpu.ops import gf
 
     cache = None if rehearsal else jaxcache.enable()
@@ -380,6 +381,9 @@ def setup(rehearsal: bool) -> None:
          native_lib=native.get_lib() is not None,
          native_error=native.build_error())
     circuit.reset_all()
+    # the checks below count this run's plan fallbacks, not those of
+    # whatever ran earlier in the process
+    plan.reset_stats()
 
 
 def one_chip(size: Size, seed: int) -> None:

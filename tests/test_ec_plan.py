@@ -382,6 +382,55 @@ def test_stats_counters_track_hits_and_misses():
 @pytest.mark.skipif(conftest.DEVICE_INJECTION,
                     reason="asserts live device-dispatch counters/plans;\
  subject absent under scripted device-fault injection")
+def test_fused_pallas_plan_records_xor_network_and_codec_compile(
+        monkeypatch):
+    """The fused plan of a registered matrix (the specialised Pallas
+    kernel, interpreted) records the matrix's XOR network as it is
+    built, and its `codec_compile` stage: compiled once, at the first
+    launch inside the launch's guard, marked on the timeline of the
+    batched dispatch it serves, and reused by later launches; the
+    network outlives a reset of the counters."""
+    from ceph_tpu.common import tracing
+    from ceph_tpu.ops import crc_pallas, gf_pallas
+
+    monkeypatch.setattr(gf_pallas, "FORCE_INTERPRET", True)
+    monkeypatch.setattr(crc_pallas, "FORCE_INTERPRET", True)
+    plan.clear()
+    plan.reset_stats()
+    codec = _codec(k=8, m=3)
+    data = RNG.integers(0, 256, (2, 8, 512), dtype=np.uint8)
+    stages = tracing.Stages(tracing.Tracer("test").start("encode_flush"))
+    token = tracing.current_dispatch.set(stages)
+    try:
+        stages.mark("dispatch_pack", annotated=True)
+        codec.encode_batch_with_crc(data)
+    finally:
+        tracing.current_dispatch.reset(token)
+        stages.close()
+    codec.encode_batch_with_crc(data)
+    st = plan.stats()
+    [(label, row)] = st["per_plan"].items()
+    assert row["executor"] == "pallas_words+crc"
+    assert (row["parity_rows"], row["xor_terms"]) == (3, 66)
+    assert row["dispatches"] == 2 and row["retraces"] == 1
+    assert st["codec_compiles"] == 1
+    assert 0 < row["codec_compile_s"] == st["codec_compile_s"]
+    names = [sp.name for sp in stages.spans]
+    at = names.index("codec_compile")
+    assert names[at - 1:at + 3] == ["dispatch_launch", "codec_compile",
+                                    "dispatch_launch", "dispatch_fetch"]
+    assert names.count("codec_compile") == 1
+    plan.reset_stats()
+    codec.encode_batch_with_crc(data)
+    st = plan.stats()
+    assert st["codec_compiles"] == 0
+    assert (st["per_plan"][label]["parity_rows"],
+            st["per_plan"][label]["xor_terms"]) == (3, 66)
+
+
+@pytest.mark.skipif(conftest.DEVICE_INJECTION,
+                    reason="asserts live device-dispatch counters/plans;\
+ subject absent under scripted device-fault injection")
 def test_fixed_profile_256_stripes_compiles_at_most_3_plans():
     """The acceptance bound: encoding 256 stripes of one fixed profile
     — arriving as ragged batches inside one power-of-two bucket plus

@@ -294,8 +294,8 @@ def test_kill_switch_compiles_nothing(monkeypatch):
 def test_prefer_schedule_policy(monkeypatch):
     sparse = xsched.compile_matrix(bmx.liberation_bitmatrix(4, 7))
     dense = xsched.compile_matrix(
-        gf.gf_matrix_to_bits(rs.reed_sol_van_matrix(8, 3)))
-    # the dense k8m3 expansion keeps the MXU matmul by op count
+        gf.gf_matrix_to_bits(rs.reed_sol_van_matrix(8, 4)))
+    # the dense k8m4 expansion keeps the MXU matmul by op count
     assert dense.xors_scheduled > 256
     assert not xsched.prefer_schedule(dense)
     # the sparse encode matrix saves < 25% (minimal-density codes
