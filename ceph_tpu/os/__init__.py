@@ -118,6 +118,12 @@ class Transaction:
     def omap_clear(self, cid: str, oid: ObjectId) -> None:
         self.ops.append(("omap_clear", cid, oid))
 
+    def omap_rmkeyrange(self, cid: str, oid: ObjectId, first: str,
+                        last: str) -> None:
+        """Remove the omap keys in [first, last); an absent object
+        has none."""
+        self.ops.append(("omap_rmkeyrange", cid, oid, first, last))
+
     def omap_setheader(self, cid: str, oid: ObjectId,
                        header: bytes) -> None:
         self.ops.append(("omap_setheader", cid, oid, bytes(header)))

@@ -514,7 +514,12 @@ class CrashSweep:
         WHOLESALE (none acked), and acked txns must never vanish.
         The model still applies per txn, so snapshots stay per-txn
         and _sync_txns maps each sync commit to the txn count it made
-        durable."""
+        durable.
+
+        The workload is drawn lazily: each txn is committed (or held
+        in its open window) before the next is drawn, so a workload
+        may build on its own commits (a PG log stages against what
+        its on_commit callbacks confirmed)."""
         live_dir = _os.path.join(self.workdir, "live")
         if _os.path.exists(live_dir):
             shutil.rmtree(live_dir)
@@ -539,24 +544,32 @@ class CrashSweep:
         self._sync_txns = []
         batch = max(int(batch), 1)
         window: List[Transaction] = []
-        work = list((workload or default_workload)(txns, seed))
-        for i, txn in enumerate(work):
+
+        def commit_window(done: int) -> None:
+            if len(window) == 1:
+                store.queue_transaction(window[0])
+            else:
+                errs = [e for e in store.submit_batch(window) if e]
+                if errs:
+                    raise errs[0]
+            self._sync_txns.append(done)
+            window.clear()
+
+        done = 0
+        for i, txn in enumerate((workload or default_workload)(txns,
+                                                               seed)):
             txn.register_on_commit(
                 lambda i=i: store.crashlog.mark(("ack", i + 1)))
             mtxn = Transaction()
             mtxn.ops = list(txn.ops)
             model.queue_transaction(mtxn)
             window.append(txn)
-            if len(window) >= batch or i == len(work) - 1:
-                if len(window) == 1:
-                    store.queue_transaction(window[0])
-                else:
-                    errs = [e for e in store.submit_batch(window) if e]
-                    if errs:
-                        raise errs[0]
-                self._sync_txns.append(i + 1)
-                window = []
+            done = i + 1
+            if len(window) >= batch:
+                commit_window(done)
             self.snapshots.append(snapshot_store(model))
+        if window:
+            commit_window(done)
         self.events = list(store.crashlog.events)
         store.umount()
         model.umount()

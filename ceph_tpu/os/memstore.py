@@ -186,6 +186,11 @@ class MemStore(ObjectStore):
                 obj.omap.pop(key, None)
         elif kind == "omap_clear":
             self._obj(op[1], op[2]).omap.clear()
+        elif kind == "omap_rmkeyrange":
+            obj = self._colls[op[1]].get(op[2])
+            if obj is not None:
+                for key in [k for k in obj.omap if op[3] <= k < op[4]]:
+                    del obj.omap[key]
         elif kind == "omap_setheader":
             self._obj(op[1], op[2], create=True).omap_header = op[3]
         else:

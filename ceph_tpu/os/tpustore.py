@@ -827,6 +827,11 @@ class TPUStore(ObjectStore):
         elif kind == "omap_clear":
             okey = self._okey(op[1], op[2])
             kvt.rm_range_keys(P_OMAP, okey + b"\0", okey + b"\1")
+        elif kind == "omap_rmkeyrange":
+            _k, cid, oid, first, last = op
+            okey = self._okey(cid, oid) + b"\0"
+            kvt.rm_range_keys(P_OMAP, okey + first.encode(),
+                              okey + last.encode())
         elif kind == "omap_setheader":
             _k, cid, oid, header = op
             onode = self._get_onode(cid, oid, create=True)

@@ -514,7 +514,14 @@ class OSDDaemon:
                      # decoded objects whose rebuilt data (or first
                      # coding shard) failed the source's crc ledger and
                      # were not installed
-                     "recover_ledger_refusals": 0}
+                     "recover_ledger_refusals": 0,
+                     # PG log staging: omap keys written or removed
+                     # (2-3 per steady write, whatever the log's
+                     # length) and whole-log rewrites (a replaced log:
+                     # merge, split, the older single-key form, or a
+                     # log staged into another shard's collection)
+                     "pglog_stage_keys": 0,
+                     "pglog_full_rewrites": 0}
         # async micro-batching encode/decode front end: concurrent EC
         # ops share plan-cached device dispatches; inline (pre-service
         # behavior) when the device tier is absent or
@@ -1566,10 +1573,9 @@ class OSDDaemon:
                                log_tail=plog.info.log_tail),
                         child_entries.get(cps, []),
                         child_missing.get(cps, {}))
-                    clog.stage(t, ccid)
-                plog.entries = keep_entries
-                plog.missing = keep_missing
-                plog.stage(t, cid)
+                    clog.stage(t, ccid, self.perf)
+                plog.replace(keep_entries, keep_missing)
+                plog.stage(t, cid, self.perf)
                 self.store.queue_transaction(t)
                 log.info("osd.%d: split %s shard %s: %d objects to %d"
                          " children", self.osd_id, parent, shard,
@@ -1972,12 +1978,11 @@ class OSDDaemon:
                     floor = self._oi_version(
                         self._read_shard(msg.pg, msg.shard, msg.oid,
                                          0, 1)[2])
-                    for le in reversed(plog.entries):
-                        if le.get("oid") == msg.oid:
-                            lv = ev(le["version"])
-                            if floor is None or lv > floor:
-                                floor = lv
-                            break
+                    le = plog.newest(msg.oid)
+                    if le is not None:
+                        lv = ev(le["version"])
+                        if floor is None or lv > floor:
+                            floor = lv
                     return floor
 
                 if msg.log_entry is not None:
@@ -2037,7 +2042,7 @@ class OSDDaemon:
                 if msg.log_entry is None and msg.oid in plog.missing:
                     self.perf["recovery_installs"] += 1
                 plog.missing.pop(msg.oid, None)
-                plog.stage(t, cid)
+                plog.stage(t, cid, self.perf)
                 # replica-side group commit: concurrent sub-writes on
                 # this shard share one barrier (safe under the
                 # per-(shard,object) lock — the await resolves only
@@ -2224,7 +2229,7 @@ class OSDDaemon:
         t = Transaction()
         if not self.store.collection_exists(cid):
             t.create_collection(cid)
-        plog.stage(t, cid)
+        plog.stage(t, cid, self.perf)
         # peering barrier: the adopted log must not reorder around an
         # open group-commit window (commit_now drains, then commits)
         await self.committer.commit_now(t)
@@ -2305,7 +2310,7 @@ class OSDDaemon:
                 t = Transaction()
                 if not self.store.collection_exists(cid):
                     t.create_collection(cid)
-                plog.stage(t, cid)
+                plog.stage(t, cid, self.perf)
                 # peering barrier: drain the window, commit inline
                 await self.committer.commit_now(t)
             # 4. push auth log to peers; collect their missing sets
@@ -3077,13 +3082,6 @@ class OSDDaemon:
                               " repaired")
         return run
 
-    @staticmethod
-    def _newest_log_entry(plog, oid: str) -> Optional[Dict[str, Any]]:
-        for le in reversed(plog.entries):
-            if le.get("oid") == oid:
-                return le
-        return None
-
     async def _scrub_object(self, state: PGState, pool, oid: str,
                             run: Dict[str, int]) -> None:
         run["objects"] += 1
@@ -3091,7 +3089,7 @@ class OSDDaemon:
         if oid in plog.missing or \
                 any(oid in m for m in state.peer_missing.values()):
             return  # recovery owns this object right now
-        newest = self._newest_log_entry(plog, oid)
+        newest = plog.newest(oid)
         if newest is not None and newest.get("op") == "delete":
             # the log says this object was DELETED: any surviving copy
             # is a straggler that missed the remove fan-out — purge it
@@ -3348,7 +3346,7 @@ class OSDDaemon:
                 # DURABLE missing marker: a crash before recovery must
                 # resume the repair, not strand reduced redundancy
                 # (scrub barrier: drained bypass, never windowed)
-                plog.stage(t, my_cid)
+                plog.stage(t, my_cid, self.perf)
                 await self.committer.commit_now(t)
             else:
                 state.peer_missing.setdefault(shard_key, {})[oid] = \
@@ -3491,7 +3489,7 @@ class OSDDaemon:
         t = Transaction()
         if not self.store.collection_exists(cid):
             t.create_collection(cid)
-        plog.stage(t, cid)
+        plog.stage(t, cid, self.perf)
         # recovery barrier: drained bypass, never windowed
         await self.committer.commit_now(t)
 
@@ -3589,7 +3587,7 @@ class OSDDaemon:
         # acked remove (found by the thrash model checker).  The
         # reference encodes deletes in the missing set as
         # "need > have, item.is_delete()" (PGLog) for the same reason.
-        newest = self._newest_log_entry(plog, oid)
+        newest = plog.newest(oid)
         if newest is not None and newest.get("op") == "delete" and \
                 ev(newest["version"]) >= need_v:
             dv = ev(newest["version"])
@@ -4125,7 +4123,7 @@ class OSDDaemon:
                 cid = self._cid(pg, my_shard)
                 t.remove(cid, ObjectId(oid))
                 plog.missing.pop(oid, None)
-                plog.stage(t, cid)
+                plog.stage(t, cid, self.perf)
                 try:
                     await self.committer.commit_now(t)
                 except KeyError:
@@ -4156,7 +4154,7 @@ class OSDDaemon:
                 cid = self._cid(pg, shard)
                 self._apply_shard_ops(t, cid, oid, ops)
                 plog.missing.pop(oid, None)
-                plog.stage(t, cid)
+                plog.stage(t, cid, self.perf)
                 # recovery install barrier: drained bypass
                 await self.committer.commit_now(t)
             else:
@@ -4662,7 +4660,7 @@ class OSDDaemon:
                     plog.trim_to(
                         int(self.config["osd_min_pg_log_entries"]))
                 plog.missing.pop(oid, None)
-                plog.stage(t, cid)
+                plog.stage(t, cid, self.perf)
                 # group commit, concurrent with the remote fan-out:
                 # the local barrier and the replica RTTs overlap, and
                 # concurrent writers share one fsync.  The task is

@@ -17,20 +17,38 @@ the shard's collection, committed in the SAME ObjectStore transaction as
 the data mutation they journal — the store's transactional atomicity
 gives the log its WAL semantics.
 
+Persistence is incremental (`PGLog::_write_log_and_missing`): one omap
+key per entry, named by its zero-padded eversion so key order is
+version order; a stage writes the entries the store is not known to
+hold and removes the keys of trimmed ones, so its cost does not grow
+with the log.  "Known to hold" means confirmed by a committed
+transaction's on_commit, never merely staged: a transaction that fails
+leaves its entries to the next stage.  The bookkeeping belongs to one
+collection.  A log that was replaced (merge, split), or is staged into
+another collection than the one it describes (the OSD's shard moved),
+is rewritten whole: its log keys are cleared and all written again.
+Logs stored in the older single-key form (`log`) still load; the next
+stage rewrites them.
+
 eversion_t = (epoch, version), ordered lexicographically.
 """
 
 from __future__ import annotations
 
+import collections
 import json
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, Optional, Set, Tuple
 
 from ceph_tpu.os import ObjectId, Transaction
 
 PGMETA_OID = "_pgmeta_"
 K_INFO = "info"
-K_LOG = "log"
+K_LOG = "log"  # the older single-key form: the whole log in one value
 K_MISSING = "missing"
+K_ENTRY_PREFIX = "log."
+# [K_LOG, K_LOG_END) holds every log key, of either form, and nothing
+# else of the pgmeta omap (info, missing, hit sets)
+K_LOG_END = "log/"
 
 Ever = Tuple[int, int]
 
@@ -41,6 +59,12 @@ def ev(v) -> Ever:
 
 
 ZERO: Ever = (0, 0)
+
+
+def entry_key(version) -> str:
+    """The omap key of the entry at `version`: zero-padded, so the
+    store's key order is version order (eversion_t::get_key_name)."""
+    return f"{K_ENTRY_PREFIX}{int(version[0]):010d}.{int(version[1]):020d}"
 
 
 def make_entry(version: Ever, prior: Ever, oid: str, op: str,
@@ -73,6 +97,18 @@ class PGInfo:
                    int(d.get("last_epoch_started", 0)))
 
 
+class _Staged:
+    """One stage awaiting its transaction's commit."""
+
+    __slots__ = ("written", "removed", "missing")
+
+    def __init__(self, written: List[str], removed: List[str],
+                 missing: Optional[Dict[str, Ever]]):
+        self.written = written
+        self.removed = removed
+        self.missing = missing
+
+
 class PGLog:
     """Ordered entries (oldest first) + info, with merge/rewind."""
 
@@ -86,6 +122,34 @@ class PGLog:
         # Persisted so a shard that crashes mid-recovery still knows what
         # it must not serve.
         self.missing: Dict[str, Ever] = missing or {}
+        # oid -> its newest entry (IndexedLog's role): the sub-write
+        # floor and scrub look an object up without scanning the log
+        self._newest: Dict[str, Dict[str, Any]] = {}
+        # Store bookkeeping, by entry key, for the collection `_cid`
+        # (None: no collection yet).  `_unsaved`: entries the store is
+        # not known to hold; every stage writes them until a commit
+        # confirms one.  `_doomed`: keys the store may hold that the
+        # log no longer wants; every stage removes them until a commit
+        # confirms it.  `_since`: for each of those keys, the stage
+        # count when it last became so; only a stage issued later
+        # confirms it, so a commit of an older stage never settles a
+        # key that changed since.  `_rewrite`: the stage count when
+        # the log was replaced; until a later stage commits, every
+        # stage clears the log keys and writes the log whole.
+        self._cid: Optional[str] = None
+        self._unsaved: Dict[str, Dict[str, Any]] = {}
+        self._doomed: Set[str] = set()
+        self._since: Dict[str, int] = {}
+        self._saved_missing: Dict[str, Ever] = {}
+        self._rewrite: Optional[int] = None
+        self._seq = 0
+        self._staged: Dict[int, _Staged] = {}
+        # seqs whose transaction committed; on_commit may fire on the
+        # store's commit thread, and a deque appends atomically
+        self._committed: Deque[int] = collections.deque()
+        # a log built in memory has no collection: its first stage
+        # writes it whole
+        self.replace(self.entries, self.missing)
 
     # -- append / trim -----------------------------------------------------
 
@@ -95,6 +159,12 @@ class PGLog:
             f"log entry {version} <= head {self.info.last_update}"
         self.entries.append(entry)
         self.info.last_update = version
+        self._newest[entry["oid"]] = entry
+        key = entry_key(version)
+        # a rewound version written again overwrites the old entry
+        self._doomed.discard(key)
+        self._unsaved[key] = entry
+        self._since[key] = self._seq
 
     def trim_to(self, keep: int) -> None:
         """Keep at most `keep` entries; advances log_tail."""
@@ -102,8 +172,34 @@ class PGLog:
             cut = self.entries[:len(self.entries) - keep]
             self.entries = self.entries[len(cut):]
             self.info.log_tail = ev(cut[-1]["version"])
+            for e in cut:
+                if self._newest.get(e["oid"]) is e:
+                    del self._newest[e["oid"]]
+                key = entry_key(e["version"])
+                self._unsaved.pop(key, None)
+                self._doomed.add(key)
+                self._since[key] = self._seq
+
+    def replace(self, entries: List[Dict[str, Any]],
+                missing: Dict[str, Ever]) -> None:
+        """Swap in another log (merge's rewind, PG split): the next
+        stage clears the log keys and rewrites it whole."""
+        self.entries = entries
+        self.missing = missing
+        self._index()
+        self._unsaved = {entry_key(e["version"]): e for e in entries}
+        self._doomed = set()
+        self._since = dict.fromkeys(self._unsaved, self._seq)
+        self._rewrite = self._seq
+
+    def _index(self) -> None:
+        self._newest = {e["oid"]: e for e in self.entries}
 
     # -- queries -----------------------------------------------------------
+
+    def newest(self, oid: str) -> Optional[Dict[str, Any]]:
+        """The newest entry for `oid`, or None if the log has none."""
+        return self._newest.get(oid)
 
     def versions(self) -> Dict[Ever, Dict[str, Any]]:
         return {ev(e["version"]): e for e in self.entries}
@@ -162,32 +258,106 @@ class PGLog:
 
         # divergent objects with no auth entry: roll back to whatever the
         # auth primary holds now (recovery source resolves it); keep ZERO
-        self.entries = [dict(e) for e in auth_entries]
+        if auth_entries != self.entries:
+            self.replace([dict(e) for e in auth_entries], self.missing)
         self.info.last_update = auth_info.last_update
         self.info.log_tail = auth_info.log_tail
         return missing
 
     # -- persistence -------------------------------------------------------
 
-    def stage(self, t: Transaction, cid: str) -> None:
-        """Write info+log+missing into the transaction (same txn as the
-        data mutation it journals)."""
-        t.omap_setkeys(cid, ObjectId(PGMETA_OID), {
-            K_INFO: json.dumps(self.info.to_dict()).encode(),
-            K_LOG: json.dumps(self.entries).encode(),
-            K_MISSING: json.dumps(
-                {k: list(v) for k, v in self.missing.items()}).encode(),
-        })
+    def stage(self, t: Transaction, cid: str,
+              counters: Optional[Dict[str, int]] = None) -> None:
+        """Write what changed since the store's last confirmed state
+        into the transaction (same txn as the data mutation it
+        journals): unsaved entries, trimmed keys, info, and missing
+        when it differs.  Staged into another collection than the one
+        the bookkeeping describes, the log is written whole there.
+        `counters` (the OSD's perf dump) gets `pglog_stage_keys` and
+        `pglog_full_rewrites`."""
+        self._settle()
+        if cid != self._cid:
+            # what `cid` holds is unknown: clear it and write it all
+            self._cid = cid
+            self.replace(self.entries, self.missing)
+        meta = ObjectId(PGMETA_OID)
+        rewrite = self._rewrite is not None
+        if rewrite:
+            t.omap_rmkeyrange(cid, meta, K_LOG, K_LOG_END)
+        keys = {k: json.dumps(e).encode()
+                for k, e in self._unsaved.items()}
+        keys[K_INFO] = json.dumps(self.info.to_dict()).encode()
+        missing = None
+        if rewrite or self.missing != self._saved_missing or any(
+                s.missing is not None for s in self._staged.values()):
+            # a stage still in flight may land another missing set
+            missing = dict(self.missing)
+            keys[K_MISSING] = json.dumps(
+                {k: list(v) for k, v in missing.items()}).encode()
+        t.omap_setkeys(cid, meta, keys)
+        removed = sorted(self._doomed)
+        if removed:
+            t.omap_rmkeys(cid, meta, removed)
+        self._seq += 1
+        self._staged[self._seq] = _Staged(
+            list(self._unsaved), removed, missing)
+        t.register_on_commit(
+            lambda seq=self._seq: self._committed.append(seq))
+        if counters is not None:
+            counters["pglog_stage_keys"] += len(keys) + len(removed)
+            counters["pglog_full_rewrites"] += int(rewrite)
+
+    def _settle(self) -> None:
+        """Fold committed stages into the store bookkeeping.  The
+        commit lane is FIFO, so a stage older than a committed one
+        that never confirmed has failed: its entries stay unsaved and
+        its removals stay due."""
+        while self._committed:
+            seq = self._committed.popleft()
+            done = self._staged.pop(seq, None)
+            if done is None:
+                continue
+            for older in [s for s in self._staged if s < seq]:
+                del self._staged[older]
+            for key in done.written:
+                if key in self._unsaved and self._since[key] < seq:
+                    del self._unsaved[key]
+                    del self._since[key]
+            for key in done.removed:
+                if key in self._doomed and self._since[key] < seq:
+                    self._doomed.discard(key)
+                    del self._since[key]
+            if done.missing is not None:
+                self._saved_missing = done.missing
+            if self._rewrite is not None and self._rewrite < seq:
+                self._rewrite = None
 
     @classmethod
     def load(cls, store, cid: str) -> "PGLog":
         try:
             omap = store.omap_get(cid, ObjectId(PGMETA_OID))
         except KeyError:
-            return cls()
-        if K_INFO not in omap:
-            return cls()
+            omap = {}
+        info = PGInfo.from_dict(json.loads(omap[K_INFO])) \
+            if K_INFO in omap else PGInfo()
         missing = {k: ev(v) for k, v in json.loads(
             omap.get(K_MISSING, b"{}")).items()}
-        return cls(PGInfo.from_dict(json.loads(omap[K_INFO])),
-                   json.loads(omap.get(K_LOG, b"[]")), missing)
+        log = cls(info, None, missing)
+        log._cid = cid
+        log._saved_missing = dict(missing)
+        log._rewrite = None
+        if K_LOG in omap:
+            # the older single-key form: the next stage rewrites it
+            log.replace(json.loads(omap[K_LOG]), log.missing)
+            return log
+        for key in sorted(k for k in omap
+                          if k.startswith(K_ENTRY_PREFIX)):
+            e = json.loads(omap[key])
+            if ev(e["version"]) <= info.log_tail:
+                # trimmed, yet still stored: the next stage removes it
+                log._doomed.add(key)
+                log._since[key] = 0
+                continue
+            log.entries.append(e)
+        log._index()
+        return log

@@ -40,7 +40,9 @@ from ceph_tpu.os.faultstore import (
     snapshot_store,
     write_image,
 )
+from ceph_tpu.os.memstore import MemStore
 from ceph_tpu.os.tpustore import TPUStore
+from ceph_tpu.osd.pg_log import PGMETA_OID, PGLog, make_entry
 
 from cluster_helpers import Cluster, tpustore_factory
 
@@ -92,6 +94,73 @@ def test_sweep_catches_store_without_sync_commit(tmp_path):
         txns=8, seed=1, double_crash=False)
     assert any("not durable" in v for v in rep["violations"]), \
         rep["violations"][:3]
+
+
+def pg_log_workload(osd_min_pg_log_entries: int):
+    """Sub-write shaped transactions: a data write plus the PG log's
+    stage in the same transaction, with the log trimmed at
+    `osd_min_pg_log_entries` and the missing set churning.  After each
+    commit the log read back from a shadow of the committed state must
+    be the in-memory log."""
+    import random
+
+    def workload(txns: int, seed: int):
+        rng = random.Random(seed)
+        log = PGLog()
+        shadow = MemStore()
+        shadow.mkfs()
+        shadow.mount()
+        t = Transaction()
+        t.create_collection("cc")
+        shadow.queue_transaction(t)
+        for i in range(txns):
+            oid = f"o{rng.randrange(6)}"
+            n = rng.randrange(1000, 9000)
+            t = Transaction()
+            if i == 0:
+                # TPUStore lists an object only once it has an onode
+                # (the model lists omap-only objects too): create the
+                # pgmeta object as the OSD's hitset persistence does
+                t.touch("cc", ObjectId(PGMETA_OID))
+            t.write("cc", ObjectId(oid), 0, n,
+                    bytes(rng.getrandbits(8) for _ in range(n)))
+            log.append(make_entry((1, i + 1), log.info.last_update,
+                                  oid, "modify", n))
+            log.trim_to(osd_min_pg_log_entries)
+            if i % 4 == 1:
+                log.missing[f"o{rng.randrange(6)}"] = (1, i)
+            elif i % 4 == 3 and log.missing:
+                log.missing.pop(sorted(log.missing)[0])
+            log.stage(t, "cc")
+            mirror = Transaction()
+            mirror.ops = list(t.ops)
+            yield t
+            # the sweep committed t before drawing the next txn
+            shadow.queue_transaction(mirror)
+            got = PGLog.load(shadow, "cc")
+            assert got.entries == log.entries
+            assert got.info.to_dict() == log.info.to_dict()
+            assert got.missing == log.missing
+
+    return workload
+
+
+@pytest.mark.parametrize("store_cls", [FaultStore, BrokenBlockStore,
+                                       BrokenCommitStore])
+def test_crash_sweep_pg_log_trims(tmp_path, store_cls):
+    """The sweep over a workload whose PG log trims inside it (entry
+    keys written and removed in the data's transaction): zero
+    violations on the real store, and both broken stores are still
+    caught."""
+    rep = CrashSweep(str(tmp_path), store_cls=store_cls).run(
+        workload=pg_log_workload(osd_min_pg_log_entries=3),
+        txns=min(SWEEP_TXNS, 12), seed=2,
+        double_crash=store_cls is FaultStore)
+    if store_cls is FaultStore:
+        assert not rep["violations"], rep["violations"][:5]
+        assert rep["points"] >= 8 * rep["txns"], rep
+    else:
+        assert rep["violations"], f"{store_cls.__name__} passed"
 
 
 def test_powercut_preserves_acked_writes(tmp_path):
@@ -455,6 +524,77 @@ def test_store_counters_scrapeable_via_prometheus(tmp_path):
                 assert "ceph_osd_store_journal_replays" in body
                 assert "ceph_osd_store_csum_read_failures" in body
                 assert "ceph_osd_store_deferred_queue_depth" in body
+            finally:
+                await mgr.stop()
+        finally:
+            await cluster.stop()
+
+    _run(main(), 240)
+
+
+def test_pg_log_staging_live_counters(tmp_path):
+    """Steady writes on a live TPUStore cluster (group commit on, logs
+    trimmed at 4): no whole-log rewrite, 2-3 omap keys per sub-write's
+    stage, every OSD's in-memory log equal to its stored log once the
+    commit lane drains, and both counters in perf dump and
+    prometheus."""
+
+    async def main():
+        from ceph_tpu.mgr import MgrDaemon
+
+        cluster = Cluster(
+            num_osds=3, osds_per_host=1,
+            osd_config={"osd_min_pg_log_entries": 4},
+            store_factory=tpustore_factory(tmp_path), persistent=True)
+        await cluster.start()
+        try:
+            await cluster.client.create_replicated_pool(
+                "pl", size=2, pg_num=4)
+            io = cluster.client.open_ioctx("pl")
+            await io.write_full("warm", b"w" * 100)
+            await cluster.wait_for_clean(timeout=90)
+
+            async def dump():
+                out = {}
+                for o in sorted(cluster.osds):
+                    rc, perf = await cluster.client.osd_command(
+                        o, {"prefix": "perf dump"})
+                    assert rc == 0
+                    out[o] = perf
+                return out
+
+            before = await dump()
+            writes = 30
+            for i in range(writes):
+                await io.write_full(f"s{i}", bytes([i]) * 3000)
+            after = await dump()
+            stages = 2 * writes  # the primary's shard and one replica
+            keys = sum(after[o]["pglog_stage_keys"]
+                       - before[o]["pglog_stage_keys"] for o in after)
+            assert 2 * stages <= keys <= 3 * stages, (keys, stages)
+            assert all(after[o]["pglog_full_rewrites"]
+                       == before[o]["pglog_full_rewrites"]
+                       for o in after)
+
+            for osd in cluster.osds.values():
+                await osd.committer.drain()
+                for pg, state in osd.pgs.items():
+                    pool = osd.osdmap.pools.get(pg.pool)
+                    if state.log is None or pool is None:
+                        continue
+                    got = PGLog.load(osd.store, osd._cid(
+                        pg, state.my_shard(osd.osd_id, pool.type)))
+                    assert got.entries == state.log.entries, pg
+                    assert got.info.to_dict() == \
+                        state.log.info.to_dict(), pg
+                    assert got.missing == state.log.missing, pg
+
+            mgr = MgrDaemon(cluster.mon.addr, config={})
+            await mgr.start()
+            try:
+                body = await mgr.modules["prometheus"].collect()
+                assert "ceph_osd_pglog_stage_keys{" in body
+                assert "ceph_osd_pglog_full_rewrites{" in body
             finally:
                 await mgr.stop()
         finally:

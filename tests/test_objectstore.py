@@ -184,6 +184,24 @@ def test_omap(store):
     assert store.omap_get(CID, OID) == {}
 
 
+def test_omap_rmkeyrange(store):
+    """[first, last) goes, keys on either side stay, and the removal
+    orders with the keys set after it in one transaction."""
+    t = Transaction()
+    t.touch(CID, OID)
+    t.omap_setkeys(CID, OID, {k: b"v" for k in
+                              ("info", "log", "log.01", "log.02", "log/",
+                               "missing")})
+    store.queue_transaction(t)
+    t = Transaction()
+    t.omap_rmkeyrange(CID, OID, "log", "log/")
+    t.omap_setkeys(CID, OID, {"log.03": b"w"})
+    t.omap_rmkeyrange(CID, ObjectId("absent"), "a", "z")
+    store.queue_transaction(t)
+    assert store.omap_get(CID, OID) == {"info": b"v", "log.03": b"w",
+                                        "log/": b"v", "missing": b"v"}
+
+
 def test_clone(store):
     _write(store, OID, 0, b"payload" * 100)
     t = Transaction()
