@@ -3357,8 +3357,8 @@ def main() -> None:
 
     ec_plan.reset_stats()
     demo = data_host[:2, :, :4096]
-    par1 = ec_plan.encode(matrix, demo, sig="bench-demo")
-    par2 = ec_plan.encode(matrix, demo, sig="bench-demo")
+    par1 = ec_plan.matmul(matrix, demo, sig="bench-demo")
+    par2 = ec_plan.matmul(matrix, demo, sig="bench-demo")
     assert par1 is not None and np.array_equal(par1, par2)
     assert np.array_equal(par1[0], gf.gf_matmul_host(matrix, demo[0])), \
         "plan-cached parity != host oracle"

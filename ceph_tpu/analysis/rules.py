@@ -790,8 +790,7 @@ def rule_unscheduled_bitmatrix_xor(a: Analyzer) -> None:
     matrix once (xsched.compile_matrix, memoized by sha256
     signature) and run the schedule through the execute seam
     (xsched.execute, which picks execute_native when the runtime is
-    built and falls back to execute_host; or the xor_sched plan
-    kind).  Pure-XOR loops only: loops that also GF-multiply
+    built and falls back to execute_host).  Pure-XOR loops only: loops that also GF-multiply
     (wide-word fields) are exempt."""
     paths = a.config.get("xsched_paths", _XSCHED_PATHS)
     exempt = a.config.get("xsched_exempt", _XSCHED_EXEMPT)
@@ -824,8 +823,7 @@ def rule_unscheduled_bitmatrix_xor(a: Analyzer) -> None:
                    "(ceph_tpu.ec.xsched.compile_matrix, memoized by "
                    "signature) and run it through the execute seam "
                    "(xsched.execute: native single-dispatch tape "
-                   "when built, execute_host fallback; or the "
-                   "xor_sched plan kind)",
+                   "when built, execute_host fallback)",
                    severity="warning",
                    symbol=_enclosing_qualname(mod, node),
                    scope_line=_scope_line(mod, node))

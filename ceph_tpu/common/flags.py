@@ -250,8 +250,6 @@ register("CEPH_TPU_INJECT_DEVICE_FAIL", None, "inject",
          "sick=ID | down_host=H (chaos device/host hazard lever)")
 
 # -- EC plan / mesh / multihost ---------------------------------------
-register("CEPH_TPU_PLAN_CACHE", "1", "startup",
-         "ExecPlan compile cache; 0 = direct jit (debug only)")
 register("CEPH_TPU_PLAN_QUARANTINE_S", "30.0", "process",
          "failed-plan quarantine seconds")
 register("CEPH_TPU_PLAN_FAIL_LIMIT", "3", "process",
@@ -296,10 +294,6 @@ register("CEPH_TPU_XSCHED", "1", "process",
          "XOR schedule compiler; 0 = naive row-walk")
 register("CEPH_TPU_NATIVE_XSCHED", "1", "process",
          "native fused-tape executor; 0 = python executor")
-register("CEPH_TPU_XSCHED_MAX_OPS", "256", "process",
-         "schedule-size cap for the compiler")
-register("CEPH_TPU_XSCHED_MIN_REDUCTION", "0.25", "process",
-         "minimum XOR reduction to prefer the schedule")
 register("CEPH_TPU_XSCHED_HOST_MAX_ONES", "4096", "process",
          "host-executor density ceiling (ones count)")
 

@@ -19,7 +19,6 @@ from ceph_tpu.ops import gf
 
 def gf_matmul(mat: np.ndarray, data: np.ndarray, use_tpu: bool,
               min_bytes: int = 1, sig: Optional[str] = None,
-              use_plan: bool = True,
               family: str = "ec-encode") -> np.ndarray:
     """(R,K) GF(2^8) matrix x (K,S) or (B,K,S) uint8, device-dispatched.
 
@@ -29,8 +28,9 @@ def gf_matmul(mat: np.ndarray, data: np.ndarray, use_tpu: bool,
     — the daemons' EC path and the multi-chip dryrun compile the same
     program; a single chip is the (1,1) mesh, and a chip whose
     ``device:<id>`` breaker is open is simply absent from the next
-    mesh build.  `sig` is the codec's plan signature; use_plan=False
-    (the --no-plan-cache toggle) dispatches with exact shapes.
+    mesh build.  `sig` is the codec's plan signature.  Below the plan
+    the mesh-direct and xla-direct dispatches take a product the plan
+    declined or failed, before the host fold.
 
     Every device attempt rides the `family` circuit breaker
     (common/circuit.py): while the breaker is open — or when the
@@ -40,7 +40,7 @@ def gf_matmul(mat: np.ndarray, data: np.ndarray, use_tpu: bool,
     """
     if use_tpu and gf.backend_available() and data.size >= min_bytes:
         if not circuit.degraded(family):
-            out = _device_matmul(mat, data, sig, use_plan, family)
+            out = _device_matmul(mat, data, sig, family)
             if out is not None:
                 return out
         else:
@@ -57,17 +57,14 @@ def gf_matmul(mat: np.ndarray, data: np.ndarray, use_tpu: bool,
 
 
 def _device_matmul(mat: np.ndarray, data: np.ndarray,
-                   sig: Optional[str], use_plan: bool,
-                   family: str) -> Optional[np.ndarray]:
+                   sig: Optional[str], family: str) -> Optional[np.ndarray]:
     """The device tiers in preference order, every dispatch guarded;
     None means 'take the host path'."""
-    if use_plan:
-        from ceph_tpu.ec import plan
+    from ceph_tpu.ec import plan
 
-        if plan.enabled():
-            out = plan.matmul(mat, data, sig=sig, family=family)
-            if out is not None:
-                return out
+    out = plan.matmul(mat, data, sig=sig, family=family)
+    if out is not None:
+        return out
     if circuit.degraded(family):     # the plan attempt may have tripped
         return None
     from ceph_tpu.parallel import backend
@@ -83,9 +80,8 @@ def _device_matmul(mat: np.ndarray, data: np.ndarray,
         # np.split hands back views of the same stripes (no byte
         # moves); each half re-dispatches under its own guard
         first_half, second_half = np.split(data, [batch // 2])
-        first = _device_matmul(mat, first_half, sig, use_plan, family)
-        second = _device_matmul(mat, second_half, sig, use_plan,
-                                family)
+        first = _device_matmul(mat, first_half, sig, family)
+        second = _device_matmul(mat, second_half, sig, family)
         if first is not None and second is not None:
             return np.concatenate([first, second], axis=0)
         return None
@@ -102,29 +98,26 @@ def _device_matmul(mat: np.ndarray, data: np.ndarray,
 
 def gf_repair_matmul(mat: np.ndarray, data: np.ndarray,
                      use_tpu: bool = True, min_bytes: int = 1,
-                     sig: Optional[str] = None, use_plan: bool = True,
+                     sig: Optional[str] = None,
                      family: str = "ec-repair") -> np.ndarray:
     """Repair-kind twin of gf_matmul for the regenerating-code path:
     helper-side projections (1 x alpha) and primary-side
     reconstructions (alpha x d) dispatch through the `repair` plan
     kind (ec/plan.py), where the small per-erasure-pattern matrix is
     a compile-time constant baked into the trace — memoized by codec
-    signature + erasure pattern, xsched-compiled when the bit
-    expansion wins.  Rides its own `ec-repair` breaker family so a
-    repair-path fault never degrades the encode/decode data path;
-    while degraded (or when the guarded dispatch fails) the call
-    takes the bit-exact numpy host fold below, so callers NEVER see
-    a device error from this entry.
+    signature + erasure pattern.  Rides its own `ec-repair` breaker
+    family so a repair-path fault never degrades the encode/decode
+    data path; while degraded (or when the guarded dispatch fails)
+    the call takes the bit-exact numpy host fold below, so callers
+    NEVER see a device error from this entry.
     """
     if use_tpu and gf.backend_available() and data.size >= min_bytes:
         if not circuit.degraded(family):
-            if use_plan:
-                from ceph_tpu.ec import plan
+            from ceph_tpu.ec import plan
 
-                if plan.enabled():
-                    out = plan.repair(mat, data, sig=sig, family=family)
-                    if out is not None:
-                        return out
+            out = plan.repair(mat, data, sig=sig, family=family)
+            if out is not None:
+                return out
         else:
             circuit.breaker(family).note_fallback()
     if data.ndim == 2:

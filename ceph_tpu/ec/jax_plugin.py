@@ -44,11 +44,9 @@ class ErasureCodeJax(ErasureCode):
         self.per_chunk_alignment = False
         self.packetsize = 2048
         self.matrix: np.ndarray | None = None
-        self._mbits_dev = None
         self._decode_cache = dispatch.LruCache(256)
         self.use_tpu = True
         self.tpu_min_bytes = 1  # kernel engages for everything unless configured
-        self.use_plan = True    # route device dispatch through ec/plan.py
         self._plan_sig: str | None = None
 
     # -- init -------------------------------------------------------------
@@ -85,7 +83,6 @@ class ErasureCodeJax(ErasureCode):
                 " the codec runs on the host", self.technique,
                 profile.get("k"), profile.get("m"))
         self.tpu_min_bytes = to_int("tpu-min-bytes", profile, "1")
-        self.use_plan = to_bool("plan-cache", profile, "true")
         self.sanity_check_k_m(self.k, self.m)
         mapping = profile.get("mapping")
         if mapping and len(mapping) != self.k + self.m:
@@ -114,14 +111,11 @@ class ErasureCodeJax(ErasureCode):
         else:
             self.matrix = rs.cauchy_good_matrix(self.k, self.m)
         if self.use_tpu:
-            import jax.numpy as jnp
-
             from ceph_tpu.ops import gf_pallas
 
             # Hot generator matrix: compiles into the specialized
             # unrolled Pallas kernel on first device dispatch.
             gf_pallas.register_matrix(self.matrix)
-            self._mbits_dev = jnp.asarray(gf.gf_matrix_to_bits(self.matrix))
 
     # -- geometry (layout-parity with ErasureCodeJerasure) ----------------
 
@@ -190,7 +184,6 @@ class ErasureCodeJax(ErasureCode):
         sig = self.plan_signature() if encode else None
         return dispatch.gf_matmul(
             mat, data, self.use_tpu, self.tpu_min_bytes, sig=sig,
-            use_plan=self.use_plan,
             # the generator matmul is the encode family; everything
             # else (inverted decode rows) is ec-decode — each trips
             # and recovers its own breaker
@@ -284,24 +277,6 @@ class ErasureCodeJax(ErasureCode):
         dmat = self._decode_matrix(tuple(have), tuple(erasures))
         return self._matmul(dmat, survivors)
 
-    def encode_many(self, datas: Sequence[np.ndarray]
-                    ) -> List[np.ndarray]:
-        """Coalesced encode: N pending (k, S_i) stripes -> parities in
-        order, folded into ONE batched device dispatch (ec/plan.py's
-        StripeCoalescer; ragged widths pad to the common bucket)."""
-        if self.w != 8 or not datas:
-            return [self._matmul(self.matrix, np.asarray(d, np.uint8))
-                    for d in datas]
-        from ceph_tpu.ec import plan
-
-        total = sum(int(np.asarray(d).size) for d in datas)
-        if self.use_tpu and self.use_plan and plan.enabled() \
-                and total >= self.tpu_min_bytes:
-            return plan.encode_coalesced(self.matrix, datas,
-                                         sig=self.plan_signature())
-        return [self._matmul(self.matrix, np.asarray(d, np.uint8))
-                for d in datas]
-
     def encode_many_with_crc(self, arrs: Sequence[np.ndarray],
                              init: int = 0
                              ) -> Optional[List[Tuple[np.ndarray,
@@ -312,12 +287,10 @@ class ErasureCodeJax(ErasureCode):
         service's flush path — many concurrent objects, one plan
         call).  None when the fused plan is unavailable (callers fall
         back per item)."""
-        if self.w != 8 or not self.use_tpu or not self.use_plan:
+        if self.w != 8 or not self.use_tpu:
             return None
         from ceph_tpu.ec import plan
 
-        if not plan.enabled():
-            return None
         arrs = [np.asarray(a, dtype=np.uint8) for a in arrs]
         if not arrs:
             return []
@@ -348,12 +321,10 @@ class ErasureCodeJax(ErasureCode):
         (B, k, S) -> (parity (B, m, S), crcs (B, k+m) uint32 seeded
         `init`).  None when the fused plan is unavailable (callers
         fall back to encode + host CRC)."""
-        if self.w != 8 or not self.use_tpu or not self.use_plan:
+        if self.w != 8 or not self.use_tpu:
             return None
         from ceph_tpu.ec import plan
 
-        if not plan.enabled():
-            return None
         out = plan.encode_with_crc(self.matrix, data,
                                    sig=self.plan_signature())
         if out is None:

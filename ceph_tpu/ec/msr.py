@@ -32,11 +32,10 @@ free symbols) turns it into one, so reads of healthy data chunks stay
 zero-decode like every other codec here.
 
 Device routing: encode/decode ride the shared dispatch.gf_matmul seam
-(plan kinds encode/matmul); repair projections and reconstructions ride
-the dedicated `repair` plan kind (dispatch.gf_repair_matmul — matrix
-baked into the trace, memoized by codec signature + erasure pattern,
-xsched-compiled when the bit expansion wins, `ec-repair` breaker family,
-bit-exact numpy host fallback).
+(plan kind matmul); repair projections and reconstructions ride the
+dedicated `repair` plan kind (dispatch.gf_repair_matmul — matrix baked
+into the trace, memoized by codec signature + erasure pattern,
+`ec-repair` breaker family, bit-exact numpy host fallback).
 """
 
 from __future__ import annotations
@@ -79,7 +78,6 @@ class ErasureCodeMsr(ErasureCode):
         self.parity_mat: Optional[np.ndarray] = None  # (m*alpha, k*alpha)
         self.use_tpu = True
         self.tpu_min_bytes = 1
-        self.use_plan = True
         self._plan_sig: Optional[str] = None
 
     # -- init -------------------------------------------------------------
@@ -102,7 +100,6 @@ class ErasureCodeMsr(ErasureCode):
         self.use_tpu = to_bool("tpu", profile, "true") and \
             gf.backend_available()
         self.tpu_min_bytes = to_int("tpu-min-bytes", profile, "1")
-        self.use_plan = to_bool("plan-cache", profile, "true")
         super().init(profile)
         self._prepare()
 
@@ -245,15 +242,13 @@ class ErasureCodeMsr(ErasureCode):
         sig = self.plan_signature() if encode else None
         return dispatch.gf_matmul(
             mat, data, self.use_tpu, self.tpu_min_bytes, sig=sig,
-            use_plan=self.use_plan,
             family="ec-encode" if encode else "ec-decode")
 
     def _repair_matmul(self, mat: np.ndarray, data: np.ndarray,
                        sig_extra: str) -> np.ndarray:
         return dispatch.gf_repair_matmul(
             mat, data, self.use_tpu, self.tpu_min_bytes,
-            sig=f"{self.plan_signature()}/{sig_extra}",
-            use_plan=self.use_plan)
+            sig=f"{self.plan_signature()}/{sig_extra}")
 
     def _to_syms(self, data: np.ndarray) -> np.ndarray:
         """(..., R, C) chunks -> (..., R*alpha, C/alpha) sub-chunk

@@ -20,15 +20,6 @@ is that layer:
   sliced back down.  Zero columns produce zero parity columns and
   padded stripes are dropped, so padding is invisible to callers while
   real traffic collapses onto a handful of plans.
-* **Stripe coalescing** — `StripeCoalescer` / `encode_coalesced` fold
-  N pending same-profile (K, S_i) encodes into ONE batched (B, K, S)
-  device call: the device-side twin of the host-path fold in
-  ec/dispatch.gf_matmul.
-* **Buffer donation** — on TPU the padded input buffer (which this
-  module itself creates, so no caller-visible aliasing) is donated to
-  the XLA executable; callers that relinquish a device array can opt
-  in with donate=True.  Donation is disabled off-TPU where XLA would
-  warn and ignore it.
 * **Fused encode + crc32c** — `encode_with_crc` returns parity AND the
   per-chunk (zero-seeded) hinfo crc32c from one dispatch instead of
   two (ECUtil::HashInfo's ledger rides the encode).
@@ -87,12 +78,10 @@ except Exception:  # pragma: no cover
 
 __all__ = [
     "bucket_batch", "bucket_bytes", "clear", "codec_signature",
-    "compute_eval", "device_platform", "enabled", "encode",
-    "encode_coalesced", "encode_with_crc", "matmul",
+    "compute_eval", "device_platform", "encode_with_crc", "matmul",
     "matrix_signature", "mesh_enabled", "mesh_dispatches",
     "mesh_info", "plan_key", "quarantine_info", "reset_stats",
-    "set_enabled", "stats", "StripeCoalescer", "tracked_jit",
-    "xor_sched_direct",
+    "stats", "tracked_jit",
 ]
 
 # ---------------------------------------------------------------------------
@@ -122,25 +111,11 @@ _per_plan: Dict[str, Dict[str, object]] = {}
 # plan label -> the XOR network of its specialised kernel, written once
 # as the plan is built; a property of the plan, so reset_stats keeps it
 _networks: Dict[str, Dict[str, int]] = {}
-_enabled = flags.enabled("CEPH_TPU_PLAN_CACHE")
 # poisoned-plan quarantine: a compiled callable that keeps failing is
 # evicted and its key blacklisted for a TTL (a single bad compile must
 # not re-trip the breaker forever while healthy plans keep serving)
 _quarantine: Dict[tuple, float] = {}         # key -> expiry (monotonic)
 _plan_failures: Dict[tuple, int] = {}        # key -> consecutive fails
-
-
-def enabled() -> bool:
-    return _enabled
-
-
-def set_enabled(on: bool) -> bool:
-    """Flip the plan cache on/off (the CLI --no-plan-cache toggle);
-    returns the previous state so callers can restore it."""
-    global _enabled
-    prev = _enabled
-    _enabled = bool(on)
-    return prev
 
 
 def stats() -> dict:
@@ -160,7 +135,6 @@ def stats() -> dict:
             **_counters,
             "plans": len(_plans),
             "quarantined_plans": len(_quarantine),
-            "enabled": _enabled,
             "per_plan": {k: {**v, **_networks.get(k, {})}
                          for k, v in _per_plan.items()},
         }
@@ -303,7 +277,6 @@ def codec_signature(technique: str, k: int, m: int, w: int,
 
 def plan_key(sig: str, kind: str, rows: int, k: int,
              batch: int, chunk_bytes: int,
-             donate: bool = False,
              mesh: Tuple[int, ...] = (),
              proc: tuple = ()) -> tuple:
     """Cache key: (codec signature, kind, bucketed shape, mesh,
@@ -325,14 +298,13 @@ def plan_key(sig: str, kind: str, rows: int, k: int,
     return (sig, kind, int(rows), int(k), bb,
             bucket_bytes(chunk_bytes) if kind not in
             ("encode_crc", "mesh_encode_crc")
-            else int(chunk_bytes), bool(donate),
+            else int(chunk_bytes),
             tuple(int(d) for d in mesh), tuple(proc))
 
 
 def _label(key: tuple) -> str:
-    sig, kind, rows, k, bb, bs, don, mesh, proc = key
+    sig, kind, rows, k, bb, bs, mesh, proc = key
     return f"{kind}[{sig}] r{rows}k{k} B{bb} S{bs}" + \
-        ("+don" if don else "") + \
         (f"+mesh{len(mesh)}" if mesh else "") + \
         (f"+hosts{proc[0]}" if proc else "")
 
@@ -537,11 +509,6 @@ def device_platform() -> Optional[str]:
         return jax.devices()[0].platform
     except Exception:  # pragma: no cover
         return None
-
-
-def _donation_usable() -> bool:
-    # off-TPU XLA ignores donation with a warning; don't ask for it
-    return device_platform() == "tpu"
 
 
 def _mbits_for(matrix: np.ndarray):
@@ -886,131 +853,6 @@ def mesh_info() -> dict:
 # ---------------------------------------------------------------------------
 
 
-# pick caches: matrix signature -> XorSchedule | ("dense", naive),
-# and schedule sig -> tracked jit.  Reached concurrently from the
-# event loop AND the encode service's to_thread workers, so every
-# access takes the lock (LruCache.peek's get-then-move_to_end is not
-# atomic under eviction); compiles/jits happen OUTSIDE it — a racing
-# pair builds twice, last write wins, both results identical
-_sched_lock = threading.Lock()
-_sched_pick = LruCache(cap=64)
-_direct_jits = LruCache(cap=32)
-
-
-def _sched_for(matrix: np.ndarray):
-    """The compiled XOR schedule of a GF(2^8) matrix's bit expansion,
-    memoized by matrix signature, or None when the kill switch is
-    off / the matrix is too dense to ever clear the op-count pick.
-    The density pre-bound matters: Paar CSE is quadratic-ish in the
-    ones count, and a wide-k expansion whose BEST case still exceeds
-    the unroll ceiling must not pay a multi-second compile on its
-    first dispatch just to be rejected.  The cache stores the
-    schedule (or the naive count for too-dense matrices) rather than
-    the verdict, so the policy knobs — `xsched.prefer_schedule` AND
-    the density bound below — are re-judged per call and stay live."""
-    if not xsched.enabled():
-        return None
-    m = np.ascontiguousarray(matrix, dtype=np.uint8)
-    msig = matrix_signature(m)
-    with _sched_lock:
-        sched = _sched_pick.peek(msig)
-    if sched is None:
-        bits = gf.gf_matrix_to_bits(m)
-        naive = int(bits.sum()) - bits.shape[0]
-        if naive // 4 > xsched._max_ops():
-            # even a 75% CSE cut (past the best the literature
-            # reports) could not fit the unroll ceiling: remember
-            # the COUNT, not the verdict, and skip the compile
-            sched = ("dense", naive)
-        else:
-            sched = xsched.compile_matrix(bits, sig=f"{msig}/bits")
-        with _sched_lock:
-            _sched_pick.put(msig, sched)
-    if isinstance(sched, tuple):        # ("dense", naive): re-judge
-        if sched[1] // 4 > xsched._max_ops():
-            return None
-        with _sched_lock:               # the ceiling was raised:
-            _sched_pick.pop(msig)       # compile on the next call
-        return _sched_for(m)
-    return sched
-
-
-def _sched_impl(sched):
-    """The device lowering of one XOR schedule: the SAME GF(2) math
-    as _gf2_matmul_bytes_impl (unpack bit planes, combine, pack) but
-    combined by the compiled XOR program instead of one dense
-    matmul — xors_scheduled region XORs instead of an (8R x 8K)
-    contraction.  Profitable exactly when xsched.prefer_schedule
-    says so (sparse bitmatrix-family expansions)."""
-    n_in = sched.n_in
-
-    def impl(data):
-        bits = gf._unpack_bits(data)          # (..., 8K, S) 0/1
-        tmp = [None] * sched.n_slots
-
-        def ref(r):
-            return bits[..., r, :] if r < n_in else tmp[r - n_in]
-
-        for dst, a, b in sched.ops:
-            tmp[dst] = jnp.bitwise_xor(ref(a), ref(b))
-        rows = []
-        for refs in sched.outputs:
-            if not refs:
-                rows.append(jnp.zeros_like(bits[..., 0, :]))
-                continue
-            acc = ref(refs[0])
-            for r in refs[1:]:
-                acc = jnp.bitwise_xor(acc, ref(r))
-            rows.append(acc)
-        return gf._pack_bits(jnp.stack(rows, axis=-2))
-
-    return impl
-
-
-def _build_xor_sched(key: tuple, sched) -> ExecPlan:
-    """The `xor_sched` plan kind: the schedule lowering jitted per
-    bucketed shape, riding the same guard/quarantine/OOM discipline
-    as every other plan.  The schedule is baked into the trace (its
-    signature IS the key prefix), so unlike the matmul kind there is
-    no runtime matrix operand."""
-    jfn = tracked_jit(_label(key), _sched_impl(sched))
-    return ExecPlan(key, jfn, "xla_xor_sched")
-
-
-def xor_sched_direct(matrix: np.ndarray):
-    """Schedule-vs-matmul pick for direct (non-plan-cached)
-    ops/gf.gf_matmul_device consumers: the jitted shape-polymorphic
-    schedule executor when the measured op count prefers it, else
-    None (caller keeps the dense bit-matmul).  Jits are memoized per
-    schedule signature and tracked, so retraces stay visible in
-    plan.stats()."""
-    if not HAVE_JAX:
-        return None
-    sched = _sched_for(np.asarray(matrix, dtype=np.uint8))
-    if sched is None or not xsched.prefer_schedule(sched):
-        return None
-    with _sched_lock:
-        fn = _direct_jits.peek(sched.sig)
-    if fn is None:
-        fn = tracked_jit(f"xor_sched_direct[{sched.sig}]",
-                         _sched_impl(sched))
-        with _sched_lock:
-            _direct_jits.put(sched.sig, fn)
-    return fn
-
-
-def _build_local_encode(key: tuple, donate: bool) -> ExecPlan:
-    """Single-dispatch XLA bit-matmul plan; the bit matrix rides as a
-    runtime operand so same-geometry matrices share the compile."""
-    kw = {"donate_argnums": (1,)} if donate else {}
-    jfn = tracked_jit(_label(key), gf._gf2_matmul_bytes_impl, **kw)
-
-    def run(mbits, padded_dev):
-        return jfn(mbits, padded_dev)
-
-    return ExecPlan(key, run, "xla_bits" + ("+donate" if donate else ""))
-
-
 def _wrap_gather(jfn: Callable) -> Callable:
     """Cross-process plans hold only their addressable output shards
     per process; materialize through the allgather so _guarded's
@@ -1025,22 +867,6 @@ def _wrap_gather(jfn: Callable) -> Callable:
         return multihost.gather(jfn(*args))
 
     return run
-
-
-def _build_mesh_encode(key: tuple, devices: tuple) -> ExecPlan:
-    """Stripe-parallel mesh twin of the local encode plan: the same
-    bit-matmul shard_mapped over a stripe-parallel mesh of the
-    surviving chips — hybrid ("dcn", "dp") when they span hosts, flat
-    ("dp",) within one (parallel/striped.py owns the kernel + the
-    logical axis rules)."""
-    from ceph_tpu.parallel import striped
-
-    mesh = striped.stripe_mesh(list(devices))
-    jfn, sharding = striped.build_mesh_encode(mesh, _label(key))
-    return ExecPlan(key, _wrap_gather(jfn),
-                    f"mesh_bits[{len(devices)}]",
-                    sharding=sharding,
-                    devices=tuple(d.id for d in devices))
 
 
 def _build_mesh_encode_crc(key: tuple, devices: tuple,
@@ -1059,29 +885,24 @@ def _build_mesh_encode_crc(key: tuple, devices: tuple,
                     devices=tuple(d.id for d in devices))
 
 
-def _mesh_encode_attempt(kind: str, family: str, matrix: np.ndarray,
-                         arr: np.ndarray, sig: str, rows: int,
-                         k: int, b: int, s: int
+def _mesh_encode_attempt(matrix: np.ndarray, arr: np.ndarray, sig: str,
+                         rows: int, k: int, b: int, s: int
                          ) -> Tuple[str, Optional[object]]:
-    """Try an encode-kind dispatch on the healthy mesh, shrinking on
-    sick chips.  Returns ("none", None) — take the single-device
-    plan — or ("ok", out) / ("oom", None).  Out is the raw padded
-    plan output; callers slice."""
+    """Try the fused encode+crc dispatch on the healthy mesh,
+    shrinking on sick chips.  Returns ("none", None) — take the
+    single-device plan — or ("ok", out) / ("oom", None).  Out is the
+    raw padded plan output; callers slice."""
     devices = _mesh_devices(b, b * k * s)
     for _attempt in range(8):       # shrink at most once per domain
         if not devices:
             return "none", None
         ids = tuple(d.id for d in devices)
-        key = plan_key(sig, kind, rows, k, b, s, mesh=ids,
+        key = plan_key(sig, "mesh_encode_crc", rows, k, b, s, mesh=ids,
                        proc=_topology())
         if _quarantined(key):
             return "none", None
-        if kind == "mesh_encode_crc":
-            plan = _get_plan(
-                key, lambda: _build_mesh_encode_crc(key, devices, s))
-        else:
-            plan = _get_plan(
-                key, lambda: _build_mesh_encode(key, devices))
+        plan = _get_plan(
+            key, lambda: _build_mesh_encode_crc(key, devices, s))
         bb, bs = key[4], key[5]
         # shard straight from host bytes in ONE device_put — landing
         # on the default device first and re-scattering would double
@@ -1094,114 +915,13 @@ def _mesh_encode_attempt(kind: str, family: str, matrix: np.ndarray,
         padded = multihost.put_global(_pad_batch(arr, bb, bs),
                                       plan.sharding)
         status, out = _mesh_dispatch(
-            family, key, plan, (_mbits_for(matrix), padded), b)
+            "fused-crc", key, plan, (_mbits_for(matrix), padded), b)
         if status in ("ok", "oom"):
             return status, out
         if status != "shrunk":
             return "none", None
         devices = _mesh_devices(b, b * k * s)  # the survivors
     return "none", None
-
-
-def encode(matrix: np.ndarray, data: np.ndarray, sig: str = None,
-           donate: Optional[bool] = None,
-           family: str = "ec-encode") -> Optional[np.ndarray]:
-    """(B, K, S) or (K, S) uint8 stripes -> parity, plan-cached.
-
-    Donation policy: None (auto) donates only the padded device buffer
-    this function itself creates from host bytes; True asserts the
-    caller relinquishes a device-resident input; False never donates.
-    Off-TPU backends never donate (XLA would ignore it).  Returns None
-    when no jax backend is available, the plan key is quarantined, or
-    the dispatch failed past the guard (callers take the bit-exact
-    host path); RESOURCE_EXHAUSTED recursively halves the batch down
-    to a single stripe before giving up.
-    """
-    if not (HAVE_JAX and gf.backend_available()):
-        return None
-    arr = np.asarray(data, dtype=np.uint8) if isinstance(
-        data, np.ndarray) else data
-    host_input = isinstance(arr, np.ndarray)
-    squeeze = False
-    if (arr.ndim if host_input else len(arr.shape)) == 2:
-        arr = arr[None]
-        squeeze = True
-    b, k, s = arr.shape
-    if s == 0:
-        return None
-    rows = int(np.asarray(matrix).shape[0])
-    sig = sig or matrix_signature(matrix)
-
-    def halve() -> Optional[np.ndarray]:
-        # OOM halving: each half re-buckets onto a smaller plan; GF
-        # parity is per-stripe independent, so the split is bit-exact
-        h = b // 2
-        first = encode(matrix, arr[:h], sig=sig, donate=donate,
-                       family=family)
-        second = encode(matrix, arr[h:], sig=sig, donate=donate,
-                        family=family)
-        if first is None or second is None:
-            return None
-        out = np.concatenate([first, second], axis=0)
-        return out[0] if squeeze else out
-
-    if host_input:
-        # mesh attempt first: big-enough host batches shard over the
-        # healthy chips (device-resident inputs follow the caller's
-        # donation contract and stay on their single device)
-        mstatus, mout = _mesh_encode_attempt(
-            "mesh_encode", family, matrix, arr, sig, rows, k, b, s)
-        if mstatus == "ok":
-            out = np.asarray(mout)[:b, :, :s]
-            return out[0] if squeeze else out
-        if mstatus == "oom" and b > 1:
-            return halve()
-    # schedule-vs-matmul pick (the xor_sched plan kind): a sparse
-    # bitmatrix-family expansion whose compiled XOR program beats the
-    # dense bit-matmul by measured op count dispatches the program
-    # instead.  The picked kind OWNS the dispatch — a failed or
-    # quarantined xor_sched plan degrades to the bit-exact HOST path
-    # (one plan key per call, exactly like the matmul kind), never to
-    # a second compiled plan
-    sched = _sched_for(np.asarray(matrix, dtype=np.uint8)) \
-        if host_input else None
-    if sched is not None and xsched.prefer_schedule(sched):
-        skey = plan_key(sched.sig, "xor_sched", rows, k, b, s)
-        if _quarantined(skey):
-            return None
-        splan = _get_plan(
-            skey, lambda: _build_xor_sched(skey, sched))
-        padded = jnp.asarray(_pad_batch(arr, skey[4], skey[5]))
-        status, out = _guarded(family, skey, splan, (padded,), b)
-        if status == "oom" and b > 1:
-            return halve()
-        if status != "ok":
-            return None
-        out = np.asarray(out)[:b, :, :s]
-        return out[0] if squeeze else out
-    eff_donate = bool(_donation_usable()
-                      and (donate or (donate is None and host_input)))
-    key = plan_key(sig, "encode", rows, k, b, s, donate=eff_donate)
-    if _quarantined(key):
-        return None
-    plan = _get_plan(
-        key, lambda: _build_local_encode(key, eff_donate))
-    bb, bs = key[4], key[5]
-    if host_input:
-        padded = jnp.asarray(_pad_batch(arr, bb, bs))
-    else:
-        # device-resident input: only donated when the caller opted in
-        # (donate=True), so no defensive copy is ever needed
-        pad = ((0, bb - b), (0, 0), (0, bs - s))
-        padded = jnp.pad(arr, pad) if (bb != b or bs != s) else arr
-    status, out = _guarded(family, key, plan,
-                           (_mbits_for(matrix), padded), b)
-    if status == "oom" and b > 1:
-        return halve()
-    if status != "ok":
-        return None
-    out = np.asarray(out)[:b, :, :s]
-    return out[0] if squeeze else out
 
 
 def _build_compute(key: tuple, weights: np.ndarray) -> ExecPlan:
@@ -1347,12 +1067,10 @@ def repair(mat: np.ndarray, data, sig: Optional[str] = None,
     The plan key hashes the MATRIX CONTENT (the caller's sig rides as
     a cache-locality extra only) because the matrix is baked into the
     trace — correctness must not depend on callers keeping sigs
-    matrix-unique.  Same schedule-vs-matmul pick as the encode kind:
-    a sparse bit expansion whose compiled XOR program wins by op
-    count dispatches as an xor_sched plan instead.  Returns None when
-    no jax backend is available, the plan key is quarantined, or the
-    guarded dispatch failed (callers take the bit-exact host path);
-    RESOURCE_EXHAUSTED halves the batch recursively first."""
+    matrix-unique.  Returns None when no jax backend is available,
+    the plan key is quarantined, or the guarded dispatch failed
+    (callers take the bit-exact host path); RESOURCE_EXHAUSTED halves
+    the batch recursively first."""
     if not (HAVE_JAX and gf.backend_available()):
         return None
     if not isinstance(data, np.ndarray):
@@ -1369,30 +1087,6 @@ def repair(mat: np.ndarray, data, sig: Optional[str] = None,
     mat = np.ascontiguousarray(np.asarray(mat, dtype=np.uint8))
     rows = mat.shape[0]
     sig = matrix_signature(mat, extra=sig or "repair")
-
-    def halve() -> Optional[np.ndarray]:
-        h = b // 2
-        first = repair(mat, arr[:h], sig=sig, family=family)
-        second = repair(mat, arr[h:], sig=sig, family=family)
-        if first is None or second is None:
-            return None
-        out = np.concatenate([first, second], axis=0)
-        return out[0] if squeeze else out
-
-    sched = _sched_for(mat)
-    if sched is not None and xsched.prefer_schedule(sched):
-        skey = plan_key(sched.sig, "xor_sched", rows, kk, b, s)
-        if _quarantined(skey):
-            return None
-        splan = _get_plan(skey, lambda: _build_xor_sched(skey, sched))
-        padded = jnp.asarray(_pad_batch(arr, skey[4], skey[5]))
-        status, out = _guarded(family, skey, splan, (padded,), b)
-        if status == "oom" and b > 1:
-            return halve()
-        if status != "ok":
-            return None
-        out = np.asarray(out)[:b, :, :s]
-        return out[0] if squeeze else out
     key = plan_key(sig, "repair", rows, kk, b, s)
     if _quarantined(key):
         return None
@@ -1400,7 +1094,13 @@ def repair(mat: np.ndarray, data, sig: Optional[str] = None,
     padded = jnp.asarray(_pad_batch(arr, key[4], key[5]))
     status, out = _guarded(family, key, plan, (padded,), b)
     if status == "oom" and b > 1:
-        return halve()
+        h = b // 2
+        first = repair(mat, arr[:h], sig=sig, family=family)
+        second = repair(mat, arr[h:], sig=sig, family=family)
+        if first is None or second is None:
+            return None
+        out = np.concatenate([first, second], axis=0)
+        return out[0] if squeeze else out
     if status != "ok":
         return None
     out = np.asarray(out)[:b, :, :s]
@@ -1416,7 +1116,7 @@ def _build_mesh_matmul(key: tuple) -> ExecPlan:
     accounting see decode dispatches too."""
     from ceph_tpu.parallel import backend
 
-    return ExecPlan(key, backend.matmul, "mesh", devices=key[7])
+    return ExecPlan(key, backend.matmul, "mesh", devices=key[6])
 
 
 def matmul(mat: np.ndarray, data, sig: str = None,
@@ -1437,7 +1137,7 @@ def matmul(mat: np.ndarray, data, sig: str = None,
         arr = arr[None]
         squeeze = True
     b, k, s = arr.shape
-    if s == 0 or s % 4:
+    if s == 0:
         return None
     mat = np.asarray(mat, dtype=np.uint8)
     rows = mat.shape[0]
@@ -1610,9 +1310,7 @@ def encode_with_crc(matrix: np.ndarray, data: np.ndarray,
     # mesh attempt first: the encode service's flush batches land
     # here — one stripe-parallel dispatch over the healthy chips,
     # parity + CRC fused on-device
-    mstatus, mout = _mesh_encode_attempt(
-        "mesh_encode_crc", "fused-crc", matrix, arr, sig, rows, k,
-        b, s)
+    mstatus, mout = _mesh_encode_attempt(matrix, arr, sig, rows, k, b, s)
     if mstatus == "ok":
         mparity, mcrcs = mout
         return (np.asarray(mparity)[:b],
@@ -1647,84 +1345,3 @@ def encode_with_crc(matrix: np.ndarray, data: np.ndarray,
     parity, crcs = out
     return (np.asarray(parity)[:b],
             np.asarray(crcs).astype(np.uint32)[:b])
-
-
-# ---------------------------------------------------------------------------
-# Stripe coalescing
-# ---------------------------------------------------------------------------
-
-
-def encode_coalesced(matrix: np.ndarray,
-                     datas: Sequence[np.ndarray], sig: str = None
-                     ) -> List[np.ndarray]:
-    """Fold N pending same-profile (K, S_i) encodes into batched
-    (B, K, S) device calls — the device twin of the host-path fold in
-    ec/dispatch.gf_matmul.  Stripes are grouped by byte bucket (one
-    2 MiB outlier must not inflate 63 pending 4 KiB stripes to its
-    width), padded to the group bucket, and each parity sliced back to
-    its own width; same-bucket traffic — the common case — stays ONE
-    dispatch.  A jax-free host fallback keeps the contract."""
-    if not datas:
-        return []
-    arrs = [np.asarray(d, dtype=np.uint8) for d in datas]
-    k = arrs[0].shape[0]
-    for a in arrs:
-        assert a.ndim == 2 and a.shape[0] == k, a.shape
-    groups: Dict[int, List[int]] = {}
-    for i, a in enumerate(arrs):
-        groups.setdefault(bucket_bytes(a.shape[1]), []).append(i)
-    out: List[Optional[np.ndarray]] = [None] * len(arrs)
-    for bs, idxs in groups.items():
-        batch = np.zeros((len(idxs), k, bs), dtype=np.uint8)
-        for row, i in enumerate(idxs):
-            batch[row, :, :arrs[i].shape[1]] = arrs[i]
-        parity = encode(matrix, batch, sig=sig)
-        if parity is None:
-            from ceph_tpu.ec import dispatch
-
-            parity = dispatch.gf_matmul(np.asarray(matrix, np.uint8),
-                                        batch, use_tpu=False)
-        for row, i in enumerate(idxs):
-            out[i] = parity[row, :, :arrs[i].shape[1]]
-    return out
-
-
-class StripeCoalescer:
-    """Accumulates pending same-profile encode requests and serves
-    them all from one batched device dispatch on flush().
-
-    The OSD-side usage shape: enqueue each small stripe as it arrives
-    (`add` returns its ticket), flush when the batch window closes,
-    then pick results up by ticket.
-    """
-
-    def __init__(self, matrix: np.ndarray, sig: str = None,
-                 max_pending: int = 64):
-        self.matrix = np.asarray(matrix, dtype=np.uint8)
-        self.sig = sig or matrix_signature(self.matrix)
-        self.max_pending = max_pending
-        self._pending: List[np.ndarray] = []
-
-    def __len__(self) -> int:
-        return len(self._pending)
-
-    @property
-    def full(self) -> bool:
-        return len(self._pending) >= self.max_pending
-
-    def add(self, data: np.ndarray) -> int:
-        """Queue one (K, S) stripe; returns its ticket (flush-order
-        index)."""
-        arr = np.asarray(data, dtype=np.uint8)
-        assert arr.ndim == 2 and arr.shape[0] == self.matrix.shape[1], \
-            arr.shape
-        self._pending.append(arr)
-        return len(self._pending) - 1
-
-    def flush(self) -> List[np.ndarray]:
-        """Encode everything pending in one batched dispatch; returns
-        parities in ticket order."""
-        pending, self._pending = self._pending, []
-        if not pending:
-            return []
-        return encode_coalesced(self.matrix, pending, sig=self.sig)

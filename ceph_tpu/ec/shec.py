@@ -110,7 +110,6 @@ class ErasureCodeShec(ErasureCode):
         self.c = 0
         self.w = 8
         self.matrix: Optional[np.ndarray] = None
-        self._mbits_dev = None
         self.use_tpu = True
         self._decode_cache = dispatch.LruCache(256)
 

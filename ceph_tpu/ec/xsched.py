@@ -24,7 +24,7 @@ byte moves.  This module is that compiler:
   re-instantiated codec or a rebuilt plan (mesh shrink, quarantine)
   never recompiles a known matrix.
 
-Three executors lower a schedule:
+Two executors lower a schedule, both on the host:
 
 * the NATIVE tier (`lower_program` + `execute_native`) flattens a
   schedule ONCE into an `XorProgram` — a flat int32 op tape of
@@ -38,11 +38,11 @@ Three executors lower a schedule:
 * the HOST tier (`execute_host`) runs the program over numpy buffer
   views — the bitmatrix trio's packet regions (models/bitmatrix
   `packet_views`) execute in place with zero stacking/transpose
-  copies; and
-* the DEVICE tier lives in ec/plan.py as the `xor_sched` plan kind
-  (the same program over bit planes, traced next to the
-  `_gf2_matmul_bytes_impl` matmul lowering) — consumers pick
-  schedule-vs-matmul by the measured op count (`prefer_schedule`).
+  copies.
+
+A schedule has no device lowering: on the chip a GF product runs as
+the Pallas word kernel or the XLA bit-matmul (ec/plan.py), which a
+traced XOR program did not beat on a v5e.
 
 `execute()` is the tier seam: native when built and enabled, host
 fallback always available.
@@ -81,7 +81,7 @@ __all__ = [
     "enabled", "execute", "execute_host", "execute_native",
     "host_compile_allowed", "lower_program", "matrix_signature",
     "naive_xor_matmul", "native_available", "native_enabled",
-    "prefer_schedule", "reset_stats", "stats",
+    "reset_stats", "stats",
 ]
 
 
@@ -105,25 +105,6 @@ def native_available() -> bool:
         return False
     lib = _native.get_lib()
     return lib is not None and hasattr(lib, "ceph_tpu_xsched_exec")
-
-
-def _max_ops() -> int:
-    """Op-count ceiling for preferring a schedule on the DEVICE tier:
-    past this, the unrolled XOR program stops beating one dense MXU
-    matmul dispatch (and the traced graph stops being small)."""
-    try:
-        return flags.flag_int("CEPH_TPU_XSCHED_MAX_OPS")
-    except ValueError:
-        return 256
-
-
-def _min_reduction() -> float:
-    """Minimum fractional XOR-count saving before a schedule is worth
-    switching lowering for (the measured-op-count pick)."""
-    try:
-        return flags.flag_float("CEPH_TPU_XSCHED_MIN_REDUCTION")
-    except ValueError:
-        return 0.25
 
 
 def _host_max_ones() -> int:
@@ -253,21 +234,6 @@ def _lower(sched: XorSchedule) -> XorProgram:
                       n_slots=n_slots,
                       n_regions=out_base + sched.n_out, tape=tape,
                       n_ops=len(ops))
-
-
-def prefer_schedule(sched: XorSchedule) -> bool:
-    """The schedule-vs-matmul pick for device lowerings, by measured
-    op count: a schedule wins when it is small enough to unroll AND
-    saves at least the configured fraction of the naive XOR count.
-    Sparse bitmatrix programs qualify; dense GF(2^8) bit expansions
-    (e.g. reed_sol_van k8m4: 323 surviving ops) keep the MXU
-    matmul."""
-    if not enabled() or sched.xors_naive <= 0:
-        return False
-    if sched.xors_scheduled > _max_ops():
-        return False
-    return sched.xors_scheduled <= \
-        (1.0 - _min_reduction()) * sched.xors_naive
 
 
 # ---------------------------------------------------------------------------

@@ -294,8 +294,8 @@ if HAVE_JAX:
         (exact: sums <= 8K <= 256 < 2^8 representable in bf16's 8-bit
         mantissa... bf16 integers are exact up to 256), then reduced mod 2.
 
-        Untraced body: ec/plan.py jits it per bucketed shape (with
-        donation on TPU); the module-level `gf2_matmul_bytes` below is
+        Untraced body: ec/plan.py traces it inside its plans per
+        bucketed shape; the module-level `gf2_matmul_bytes` below is
         the fixed-shape compat wrapper for direct/shard_map callers.
         """
         bits = _unpack_bits(data).astype(jnp.bfloat16)
@@ -322,22 +322,14 @@ if HAVE_JAX:
         """(R,K) GF(2^8) matrix x (..., K, S) uint8 through the fastest
         device path: the packed-word xtime Pallas kernel on TPU for
         host-side (numpy) inputs (ops/gf_pallas.py — word-layout entry,
-        ~360 GiB/s on a v5e), then schedule-vs-matmul by measured op
-        count — a sparse bit expansion whose compiled XOR schedule
-        (ec/xsched.py) beats the dense contraction runs as the XOR
-        program (ec/plan.xor_sched_direct), everything else as the XLA
-        bit-decomposition matmul (a device-side uint8->int32 relayout
-        would cost more than the encode)."""
+        ~360 GiB/s on a v5e), else the XLA bit-decomposition matmul (a
+        device-side uint8->int32 relayout would cost more than the
+        encode)."""
         from ceph_tpu.ops import gf_pallas
 
         if isinstance(data, np.ndarray) and gf_pallas.supported(
                 np.shape(data)):
             return gf_pallas.gf_matmul_pallas(m, data)
-        from ceph_tpu.ec import plan  # lazy: plan imports this module
-
-        jfn = plan.xor_sched_direct(m)
-        if jfn is not None:
-            return jfn(jnp.asarray(data, dtype=jnp.uint8))
         mbits = jnp.asarray(gf_matrix_to_bits(m))
         return gf2_matmul_bytes(mbits, jnp.asarray(data, dtype=jnp.uint8))
 

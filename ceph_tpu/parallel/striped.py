@@ -108,24 +108,6 @@ def stripe_mesh(devices) -> Mesh:
     return multihost.hybrid_stripe_mesh(devices)
 
 
-def build_mesh_encode(mesh: Mesh, label: str):
-    """Compiled mesh EC encode: (mbits, (B, k, S)) -> (B, m, S) with
-    the stripe batch sharded over "dp".  The GF(2) bit-matmul is
-    purely local per chip (the byte axis is elementwise for the
-    code), so there is no collective at all — near-linear scaling is
-    the expected shape.  Returns (jitted_fn, input_sharding); callers
-    device_put the batch with the sharding first (the pre-sharded-
-    input discipline, SNIPPETS [3]) so dispatch never re-lands bytes
-    on host between stages."""
-    from ceph_tpu.ec import plan
-
-    data_spec = logical_spec("stripe", "shard", "byte", mesh=mesh)
-    fn = _shard_map(gf._gf2_matmul_bytes_impl, mesh=mesh,
-                    in_specs=(P(), data_spec), out_specs=data_spec)
-    return (plan.tracked_jit(label, fn),
-            NamedSharding(mesh, data_spec))
-
-
 def build_mesh_encode_crc(mesh: Mesh, chunk_bytes: int, label: str):
     """Compiled mesh fused encode + per-chunk zero-seeded crc32c:
     (mbits, (B, k, S)) -> (parity (B, m, S), crcs (B, k+m) packed

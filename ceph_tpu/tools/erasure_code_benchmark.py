@@ -5,10 +5,9 @@ Reference: /root/reference/src/test/erasure-code/ceph_erasure_code_benchmark.cc
 line `<seconds>\t<KiB processed>` so qa/workunits/erasure-code/bench.sh can
 drive this tool unchanged.
 
-Extension over the reference: --plan-cache (default) / --no-plan-cache
-toggles the ExecPlan dispatch cache (ceph_tpu.ec.plan) so the win is
-measurable from the CLI; plan-cache hit/miss/retrace counters print to
-stderr after the timing line (stdout keeps the reference contract).
+Extension over the reference: the ExecPlan dispatch cache's
+(ceph_tpu.ec.plan) hit/miss/retrace counters print to stderr after the
+timing line (stdout keeps the reference contract).
 """
 
 from __future__ import annotations
@@ -42,15 +41,6 @@ def parse_args(argv: List[str]) -> argparse.Namespace:
                    choices=("random", "exhaustive"), dest="erasures_generation")
     p.add_argument("-P", "--parameter", action="append", default=[],
                    help="add a profile parameter key=value")
-    p.add_argument("--plan-cache", dest="plan_cache",
-                   action="store_true", default=None,
-                   help="dispatch through the ExecPlan cache "
-                        "(the default unless the CEPH_TPU_PLAN_CACHE=0 "
-                        "kill switch is set; see ceph_tpu.ec.plan)")
-    p.add_argument("--no-plan-cache", dest="plan_cache",
-                   action="store_false",
-                   help="bypass the plan cache: every shape "
-                        "dispatches/retraces exactly as requested")
     return p.parse_args(argv)
 
 
@@ -84,21 +74,13 @@ def run(argv: List[str]) -> int:
     args = parse_args(argv)
     from ceph_tpu.ec import plan
 
-    # tri-state: an explicit flag overrides for this run only; no flag
-    # leaves the process state (incl. the CEPH_TPU_PLAN_CACHE=0 kill
-    # switch) untouched
-    was_enabled = (plan.set_enabled(args.plan_cache)
-                   if args.plan_cache is not None else None)
     plan.reset_stats()
     try:
         return _run_timed(args)
     finally:
         stats = plan.stats()
-        print(f"plan-cache: enabled={plan.enabled()}"
-              f" hits={stats['hits']} misses={stats['misses']}"
+        print(f"plan-cache: hits={stats['hits']} misses={stats['misses']}"
               f" retraces={stats['retraces']}", file=sys.stderr)
-        if was_enabled is not None:
-            plan.set_enabled(was_enabled)
 
 
 def _run_timed(args: argparse.Namespace) -> int:

@@ -1,5 +1,5 @@
 """Clean twin: EC entry points compile through the ExecPlan cache
-(ceph_tpu.ec.plan) — bucketed, counted, donated where safe."""
+(ceph_tpu.ec.plan) — bucketed and counted."""
 
 from ceph_tpu.ec import plan
 
@@ -12,4 +12,4 @@ encode_fn = plan.tracked_jit("fx.encode", encode_stripes)
 
 
 def batched_parity(matrix, stripes):
-    return plan.encode(matrix, stripes)
+    return plan.matmul(matrix, stripes)

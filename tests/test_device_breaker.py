@@ -284,7 +284,7 @@ def test_oom_halving_bit_exact_vs_numpy_oracle(monkeypatch):
     data = rng.integers(0, 256, (32, 4, 64), dtype=np.uint8)
     oracle = ec_dispatch.gf_matmul(mat, data, use_tpu=False)
     monkeypatch.setenv("CEPH_TPU_INJECT_DEVICE_FAIL", "oom=4")
-    out = plan.encode(mat, data)
+    out = plan.matmul(mat, data, family="ec-encode")
     # the split bottomed out at batches <= 4, each dispatched on
     # device, and the reassembled parity is bit-exact
     assert out is not None and np.array_equal(out, oracle)
@@ -317,7 +317,7 @@ def test_oom_at_single_stripe_floor_falls_back_to_host(monkeypatch):
     monkeypatch.setenv("CEPH_TPU_INJECT_DEVICE_FAIL", "oom=0")
     # every batch size OOMs, even a single stripe: the floor gives up
     # and the caller rides the host path — still bit-exact, no raise
-    assert plan.encode(mat, data) is None
+    assert plan.matmul(mat, data, family="ec-encode") is None
     out = ec_dispatch.gf_matmul(mat, data, use_tpu=True)
     assert np.array_equal(out, host)
 
@@ -351,9 +351,12 @@ def test_poisoned_plan_quarantine_and_expiry(monkeypatch):
     # keep the breaker out of the way: this test is about the PLAN
     # failure counter, which needs failures to keep reaching the key
     circuit.breaker("ec-encode").fail_threshold = 10_000
+    # one device: a failed mesh dispatch would probe and retire chips
+    # first (test_mesh_plan's subject) and move the plan to a new key
+    monkeypatch.setenv("CEPH_TPU_MESH", "0")
     monkeypatch.setenv("CEPH_TPU_INJECT_DEVICE_FAIL", "1.0")
-    for _ in range(3):                    # CEPH_TPU_PLAN_FAIL_LIMIT
-        assert plan.encode(mat, data) is None
+    for _ in range(3):                   # CEPH_TPU_PLAN_FAIL_LIMIT
+        assert plan.matmul(mat, data, family="ec-encode") is None
     st = plan.stats()
     assert st["quarantines"] == 1 and st["quarantined_plans"] == 1
     assert plan.quarantine_info()["entries"]
@@ -361,10 +364,10 @@ def test_poisoned_plan_quarantine_and_expiry(monkeypatch):
     # callers keep riding the host path without rebuilding the plan
     monkeypatch.delenv("CEPH_TPU_INJECT_DEVICE_FAIL")
     misses_before = plan.stats()["misses"]
-    assert plan.encode(mat, data) is None
+    assert plan.matmul(mat, data, family="ec-encode") is None
     assert plan.stats()["misses"] == misses_before  # cache untouched
     time.sleep(0.3)                       # TTL expiry releases the key
-    out = plan.encode(mat, data)
+    out = plan.matmul(mat, data, family="ec-encode")
     assert out is not None
     assert np.array_equal(
         out, ec_dispatch.gf_matmul(mat, data, use_tpu=False))

@@ -149,7 +149,7 @@ def test_pallas_specialized_kernel_matches_oracle(pallas_interpret):
     assert np.array_equal(got, gf.gf_matmul_ref(mat, data))
 
 
-def test_pallas_decode_matrix_generic_path(pallas_interpret):
+def test_pallas_decode_matrix_generic_path(pallas_interpret, monkeypatch):
     """Decode matrices (unregistered) run the generic SMEM kernel and
     reconstruct erased chunks bit-exactly."""
     gfp = pallas_interpret
@@ -162,6 +162,10 @@ def test_pallas_decode_matrix_generic_path(pallas_interpret):
     chunks = np.concatenate([data, parity], axis=0)
     have = [1, 2, 3, 4]
     dmat = rs.decode_matrix(mat, k, [0], have)
+    # the registry is process-wide: an earlier test's k=4 m=1 codec
+    # registers the same all-ones row as its generator
+    monkeypatch.delitem(gfp._registered, gfp._coeff_key(dmat),
+                        raising=False)
     assert gfp._coeff_key(dmat) not in gfp._registered
     got = gfp.gf_matmul_pallas(dmat, chunks[have])
     assert np.array_equal(got[0], data[0])

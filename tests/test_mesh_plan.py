@@ -83,14 +83,15 @@ def test_mesh_encode_bitexact_vs_single_device_and_host(
     data = RNG.integers(0, 256, (b, 4, s), dtype=np.uint8)
     want = _host_parity(mat, data)
 
-    meshed = plan.encode(mat, data, sig=f"mesh-{b}-{s}")
-    assert meshed is not None and np.array_equal(meshed, want)
+    meshed = plan.encode_with_crc(mat, data, sig=f"mesh-{b}-{s}")
+    assert meshed is not None and np.array_equal(meshed[0], want)
+    assert np.array_equal(meshed[1], _host_crcs(data, want))
     assert plan.stats()["mesh_dispatches"] >= 1
 
     monkeypatch.setenv("CEPH_TPU_MESH", "0")
-    single = plan.encode(mat, data, sig=f"mesh-{b}-{s}")
-    assert single is not None and np.array_equal(single, want)
-    assert np.array_equal(meshed, single)
+    single = plan.encode_with_crc(mat, data, sig=f"mesh-{b}-{s}")
+    assert single is not None and np.array_equal(single[0], want)
+    assert np.array_equal(meshed[1], single[1])
 
 
 @pytest.mark.skipif(conftest.DEVICE_INJECTION,
@@ -127,8 +128,8 @@ def test_small_batches_stay_single_device():
     pay an 8-chip fan-out."""
     mat = rs.reed_sol_van_matrix(4, 2)
     data = RNG.integers(0, 256, (1, 4, 512), dtype=np.uint8)
-    out = plan.encode(mat, data, sig="tiny")
-    assert out is not None and np.array_equal(out,
+    out = plan.encode_with_crc(mat, data, sig="tiny")
+    assert out is not None and np.array_equal(out[0],
                                               _host_parity(mat, data))
     assert plan.stats()["mesh_dispatches"] == 0
 
@@ -140,8 +141,8 @@ def test_mesh_min_bytes_gate(monkeypatch):
     monkeypatch.setenv("CEPH_TPU_MESH_MIN_BYTES", str(1 << 30))
     mat = rs.reed_sol_van_matrix(4, 2)
     data = RNG.integers(0, 256, (16, 4, 512), dtype=np.uint8)
-    out = plan.encode(mat, data, sig="gated")
-    assert out is not None and np.array_equal(out,
+    out = plan.encode_with_crc(mat, data, sig="gated")
+    assert out is not None and np.array_equal(out[0],
                                               _host_parity(mat, data))
     assert plan.stats()["mesh_dispatches"] == 0
 
@@ -225,10 +226,10 @@ def test_probe_devices_attributes_only_the_sick_chip(monkeypatch):
 
 def test_mesh_plan_keys_are_device_set_aware():
     sig = "a" * 16
-    base = plan.plan_key(sig, "mesh_encode", 2, 4, 16, 1024)
-    m1 = plan.plan_key(sig, "mesh_encode", 2, 4, 16, 1024,
+    base = plan.plan_key(sig, "matmul", 2, 4, 16, 1024)
+    m1 = plan.plan_key(sig, "matmul", 2, 4, 16, 1024,
                        mesh=(0, 1, 2, 3))
-    m2 = plan.plan_key(sig, "mesh_encode", 2, 4, 16, 1024,
+    m2 = plan.plan_key(sig, "matmul", 2, 4, 16, 1024,
                        mesh=(0, 1, 2))
     assert len({base, m1, m2}) == 3
     # whole stripes per chip: the pow2 bucket rounds UP to a multiple

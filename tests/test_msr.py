@@ -221,7 +221,7 @@ def test_repair_host_fallback_parity(monkeypatch):
 
 def test_repair_plan_kind():
     """The repair matmul rides the ExecPlan cache as its own `repair`
-    (or compiled xor_sched) kind, bit-exact vs the host oracle."""
+    kind, bit-exact vs the host oracle."""
     jax = pytest.importorskip("jax")  # noqa: F841
     from ceph_tpu.ec import plan
 
@@ -234,7 +234,7 @@ def test_repair_plan_kind():
     ref = np.stack([gf.gf_matmul_ref(mat, data[i]) for i in range(2)])
     assert np.array_equal(out, ref)
     labels = [lbl for lbl in plan.stats()["per_plan"]
-              if "repair" in lbl or "xor_sched" in lbl]
+              if lbl.startswith("repair[")]
     assert labels
 
 

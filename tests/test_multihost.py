@@ -121,7 +121,7 @@ def _host_oracle_results():
 def _plan_results():
     return _case_results(
         lambda m, d, s: plan.encode_with_crc(m, d, sig=s),
-        lambda m, d, s: plan.encode(m, d, sig=s))
+        lambda m, d, s: plan.matmul(m, d, sig=s))
 
 
 _WORKER_SRC = textwrap.dedent("""
@@ -275,26 +275,26 @@ def test_plan_key_topology_stability():
     """The process-topology element: identical topologies key
     identically; different cluster shapes over the same chips never
     collide; the trivial single-host shape keys exactly as the
-    pre-multihost 8-tuple form did (same leading elements, empty
+    pre-multihost form did (same leading elements, empty
     proc)."""
     sig = "b" * 16
     topo_2x4 = (2, ((0, (0, 1, 2, 3)), (1, (4, 5, 6, 7))))
     topo_4x2 = (4, ((0, (0, 1)), (1, (2, 3)), (2, (4, 5)),
                     (3, (6, 7))))
-    base = plan.plan_key(sig, "mesh_encode", 2, 4, 16, 1024,
+    base = plan.plan_key(sig, "matmul", 2, 4, 16, 1024,
                          mesh=tuple(range(8)))
-    k24 = plan.plan_key(sig, "mesh_encode", 2, 4, 16, 1024,
+    k24 = plan.plan_key(sig, "matmul", 2, 4, 16, 1024,
                         mesh=tuple(range(8)), proc=topo_2x4)
-    k42 = plan.plan_key(sig, "mesh_encode", 2, 4, 16, 1024,
+    k42 = plan.plan_key(sig, "matmul", 2, 4, 16, 1024,
                         mesh=tuple(range(8)), proc=topo_4x2)
     assert len({base, k24, k42}) == 3
-    assert k24 == plan.plan_key(sig, "mesh_encode", 2, 4, 16, 1024,
+    assert k24 == plan.plan_key(sig, "matmul", 2, 4, 16, 1024,
                                 mesh=tuple(range(8)), proc=topo_2x4)
     # single-host: proc is empty and the key round-trips through
     # JSON identically (process-stable, like the PR-2 stability test)
     assert base[-1] == ()
-    norm = json.loads(json.dumps(list(base)[:7]))
-    assert norm == list(base)[:7]
+    norm = json.loads(json.dumps(list(base)[:6]))
+    assert norm == list(base)[:6]
 
 
 def test_topology_signature_shapes(monkeypatch):

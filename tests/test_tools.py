@@ -26,10 +26,12 @@ from ceph_tpu.tools import (
 def test_benchmark_encode(capsys):
     assert ecb.run(["-p", "jerasure", "-P", "k=4", "-P", "m=2",
                     "-s", "65536", "-i", "2"]) == 0
-    out = capsys.readouterr().out.strip()
-    seconds, kib = out.split("\t")
+    cap = capsys.readouterr()
+    seconds, kib = cap.out.strip().split("\t")
     assert float(seconds) > 0
     assert int(kib) == 2 * 64
+    # the plan-cache counters go to stderr, off the one-line contract
+    assert "plan-cache: hits=" in cap.err and "retraces=" in cap.err
 
 
 def test_benchmark_decode_random(capsys):
@@ -50,26 +52,6 @@ def test_benchmark_decode_erased_list(capsys):
                     "-s", "8192", "--erased", "0", "--erased", "3"]) == 0
     out = capsys.readouterr().out
     assert "(0)" in out and "(3)" in out  # display_chunks marks erased
-
-
-def test_benchmark_plan_cache_toggle(capsys):
-    """--plan-cache/--no-plan-cache flip the ExecPlan cache and the
-    retrace counters print to stderr; stdout keeps the reference
-    one-line contract either way."""
-    from ceph_tpu.ec import plan
-
-    assert ecb.run(["-p", "ec_jax", "-P", "k=4", "-P", "m=2",
-                    "-s", "16384", "-i", "2", "--plan-cache"]) == 0
-    cap = capsys.readouterr()
-    assert len(cap.out.strip().splitlines()) == 1 and "\t" in cap.out
-    assert "plan-cache: enabled=True" in cap.err
-    assert "retraces=" in cap.err
-
-    assert ecb.run(["-p", "ec_jax", "-P", "k=4", "-P", "m=2",
-                    "-s", "16384", "--no-plan-cache"]) == 0
-    cap = capsys.readouterr()
-    assert "plan-cache: enabled=False" in cap.err
-    assert plan.enabled()  # the toggle was restored after the run
 
 
 # -- ceph-erasure-code-tool ------------------------------------------------

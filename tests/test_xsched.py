@@ -3,8 +3,7 @@ bit-exactness across the bitmatrix family (all techniques x legal w
 values x every 1- and 2-erasure pattern), GF(2^8) bit-expansion
 equivalence on ragged chunk sizes, the CEPH_TPU_XSCHED=0 kill-switch
 parity leg through a live cluster, the shared decode-rows cache
-(cross-instance hits), schedule survival across plan rebuilds, and
-the device-tier `xor_sched` plan kind next to the matmul lowering.
+(cross-instance hits), and schedule survival across plan rebuilds.
 """
 
 from __future__ import annotations
@@ -15,7 +14,6 @@ import itertools
 import numpy as np
 import pytest
 
-import conftest
 from ceph_tpu.ec import dispatch, plan, xsched
 from ceph_tpu.ec.registry import create_erasure_code
 from ceph_tpu.models import bitmatrix as bmx
@@ -25,9 +23,6 @@ from ceph_tpu.ops import gf
 from cluster_helpers import Cluster
 
 RNG = np.random.default_rng(0xEC5)
-
-needs_jax = pytest.mark.skipif(not gf.backend_available(),
-                               reason="no jax backend")
 
 
 def _exec(sched: xsched.XorSchedule, pk: np.ndarray) -> np.ndarray:
@@ -287,71 +282,3 @@ def test_kill_switch_compiles_nothing(monkeypatch):
     codec.encode(range(n), payload)
     st = plan.stats()["xsched"]
     assert st["compiled"] == 0 and st["enabled"] is False
-
-
-# -- the schedule-vs-matmul pick ---------------------------------------
-
-def test_prefer_schedule_policy(monkeypatch):
-    sparse = xsched.compile_matrix(bmx.liberation_bitmatrix(4, 7))
-    dense = xsched.compile_matrix(
-        gf.gf_matrix_to_bits(rs.reed_sol_van_matrix(8, 4)))
-    # the dense k8m4 expansion keeps the MXU matmul by op count
-    assert dense.xors_scheduled > 256
-    assert not xsched.prefer_schedule(dense)
-    # the sparse encode matrix saves < 25% (minimal-density codes
-    # are near-optimal already): not preferred by default...
-    assert not xsched.prefer_schedule(sparse)
-    # ...but the knobs are live
-    monkeypatch.setenv("CEPH_TPU_XSCHED_MIN_REDUCTION", "0")
-    assert xsched.prefer_schedule(sparse)
-    monkeypatch.setenv("CEPH_TPU_XSCHED", "0")
-    assert not xsched.prefer_schedule(sparse)
-
-
-@needs_jax
-@pytest.mark.skipif(conftest.DEVICE_INJECTION,
-                    reason="asserts live device-dispatch counters/plans;\
- subject absent under scripted device-fault injection")
-def test_xor_sched_plan_kind_next_to_matmul(monkeypatch):
-    """The device tier: a matrix whose schedule wins by measured op
-    count dispatches through the `xor_sched` plan kind, bit-exact
-    with the host oracle; the kill switch pins the matmul kind."""
-    monkeypatch.setenv("CEPH_TPU_XSCHED_MIN_REDUCTION", "0")
-    mat = rs.reed_sol_van_matrix(4, 2)
-    data = RNG.integers(0, 256, (2, 4, 256), dtype=np.uint8)
-    want = np.stack([gf.gf_matmul_host(mat, data[i])
-                     for i in range(2)])
-    plan.clear()
-    plan.reset_stats()
-    out = plan.encode(mat, data)
-    assert out is not None and np.array_equal(out, want)
-    labels = plan.stats()["per_plan"]
-    assert any(lbl.startswith("xor_sched") for lbl in labels), labels
-    # second dispatch in the bucket: a plan-cache hit, no retrace
-    assert plan.encode(mat, data) is not None
-    assert plan.stats()["hits"] >= 1
-    # kill switch: same math through the matmul kind, bit-identical
-    monkeypatch.setenv("CEPH_TPU_XSCHED", "0")
-    plan.clear()
-    plan.reset_stats()
-    out2 = plan.encode(mat, data)
-    assert out2 is not None and np.array_equal(out2, want)
-    assert not any(lbl.startswith("xor_sched")
-                   for lbl in plan.stats()["per_plan"])
-
-
-@needs_jax
-def test_gf_matmul_device_consumer_pick(monkeypatch):
-    """ops/gf.gf_matmul_device consumers pick schedule-vs-matmul by
-    measured op count: the direct (non-plan) entry routes a winning
-    matrix through the jitted schedule executor, bit-exactly."""
-    monkeypatch.setenv("CEPH_TPU_XSCHED_MIN_REDUCTION", "0")
-    mat = rs.cauchy_good_matrix(4, 2)
-    assert plan.xor_sched_direct(mat) is not None
-    data = RNG.integers(0, 256, (4, 128), dtype=np.uint8)
-    out = np.asarray(gf.gf_matmul_device(mat, data))
-    assert np.array_equal(out, gf.gf_matmul_ref(mat, data))
-    monkeypatch.setenv("CEPH_TPU_XSCHED", "0")
-    assert plan.xor_sched_direct(mat) is None
-    out2 = np.asarray(gf.gf_matmul_device(mat, data))
-    assert np.array_equal(out2, out)
