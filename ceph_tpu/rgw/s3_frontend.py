@@ -201,6 +201,7 @@ class S3Frontend:
             except (Exception, asyncio.TimeoutError):
                 pass
             self._server = None
+        self.rgw.stop_etag_pool()
 
     # -- HTTP plumbing -----------------------------------------------------
 

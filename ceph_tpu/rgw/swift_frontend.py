@@ -66,6 +66,7 @@ class SwiftFrontend:
             except (Exception, asyncio.TimeoutError):
                 pass
             self._server = None
+        self.rgw.stop_etag_pool()
 
     # -- HTTP plumbing (same shape as the S3 frontend) --------------------
 
